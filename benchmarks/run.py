@@ -18,6 +18,8 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.paper
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     jax.config.update('jax_enable_x64', True)
 

@@ -6,6 +6,10 @@ adjoint/kernel implementations (the paper's Sec. VI correctness check).
 """
 import argparse
 
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
+
 import jax
 
 jax.config.update('jax_enable_x64', True)
@@ -78,6 +82,9 @@ def main():
               f'{t["etot"]:>14.6f}')
     for ev in cache.get('recovery_events', []):
         print(f'recovery: step {ev.step} {ev.kind} {ev.detail}')
+    if not thermo:
+        print('no steps run: no NVE energy drift to report')
+        return
     drift = abs(thermo[-1]['etot'] - thermo[0]['etot'])
     scale = max(abs(thermo[0]['etot']), 1.0)
     print(f'NVE energy drift: {drift:.3e} eV ({drift / scale:.2e} relative)')

@@ -37,7 +37,7 @@ def main():
     print(f'bucket table: {[b.key for b in TABLE.all_buckets()]}')
     print(f'poison plan: {plan or "(none)"}')
 
-    srv = ForceServer(TABLE, impl=args.impl, interpret=True)
+    srv = ForceServer(TABLE, impl=args.impl)
     health = run_open_loop(srv, schedule)
 
     print('\nper-request outcomes:')

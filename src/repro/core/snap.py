@@ -158,35 +158,59 @@ def energy_from_ylist(cfg: SnapConfig, ulisttot, ylist, beta, beta0):
 
 def energy_forces_adjoint(cfg: SnapConfig, beta, beta0, dx, dy, dz,
                           nbr_idx, mask, with_energy: bool = True,
-                          energy_via_z: bool = False, shard=None):
+                          energy_via_z: bool = False, shard=None,
+                          atom_chunks: int = 1):
     """The paper's refactored pipeline: U -> Y -> fused dE -> forces.
 
     shard: optional ``(axis_name, n_shards)`` when running as the per-shard
     body of an atom-sharded ``shard_map`` — rows are local atoms, nbr_idx is
     global, and force assembly reduce-scatters across shards.  The returned
     energy is then this shard's partial sum (the wrapper psums it).
+
+    atom_chunks: run the per-atom stages (U, Y, dE, energy) over this many
+    equal blocks of atom rows in turn (``lax.map``), so that only one
+    block's per-pair planes are live — what lets the f64 oracle run at
+    the paper's 2000-atom 2J=14 size in host memory.  Forces are assembled
+    once from all blocks; natoms must divide by ``atom_chunks``.
     """
     idx = cfg.index
-    natoms = dx.shape[0]
+    natoms, nnbor = dx.shape
     axis_name, n_shards = shard if shard is not None else (None, 1)
-    geom, dgeom, ok = _pair_geometry(cfg, dx, dy, dz, mask, grad=True)
-    u, du = compute_dulist(geom, dgeom, idx, cfg.dtype)
-    ut = compute_ulisttot(u, geom.sfac, ok, idx, cfg.wself)
-    y = bs.compute_ylist(ut, beta, idx)
-    atom_of_pair = jnp.repeat(jnp.arange(natoms), dx.shape[1])
-    dedr = bs.compute_dedr(
-        du.reshape(-1, 3, idx.idxu_max), y, atom_of_pair, idx)
-    forces = assemble_forces(
-        dedr.reshape(natoms, -1, 3), nbr_idx, ok, natoms * n_shards,
-        axis_name=axis_name)
+
+    def rows(dx, dy, dz, mask):
+        geom, dgeom, ok = _pair_geometry(cfg, dx, dy, dz, mask, grad=True)
+        u, du = compute_dulist(geom, dgeom, idx, cfg.dtype)
+        ut = compute_ulisttot(u, geom.sfac, ok, idx, cfg.wself)
+        y = bs.compute_ylist(ut, beta, idx)
+        n = dx.shape[0]
+        atom_of_pair = jnp.repeat(jnp.arange(n), nnbor)
+        dedr = bs.compute_dedr(
+            du.reshape(-1, 3, idx.idxu_max), y, atom_of_pair, idx)
+        e_atom = None
+        if with_energy and energy_via_z:
+            z = bs.compute_zlist(ut, idx)
+            b = bs.compute_blist(ut, z, idx, cfg.bzero_flag)
+            e_atom = beta0 + b @ beta.astype(b.dtype)
+        elif with_energy:
+            e_atom = energy_from_ylist(cfg, ut, y, beta, beta0)
+        return dedr.reshape(n, nnbor, 3), ok, e_atom
+
+    if atom_chunks == 1:
+        dedr, ok, e_atom = rows(dx, dy, dz, mask)
+    else:
+        if natoms % atom_chunks:
+            raise ValueError(f'natoms={natoms} must divide by '
+                             f'atom_chunks={atom_chunks}')
+        blocks = jax.tree.map(
+            lambda a: a.reshape(atom_chunks, -1, *a.shape[1:]),
+            (dx, dy, dz, mask))
+        out = jax.lax.map(lambda a: rows(*a), blocks)
+        dedr, ok, e_atom = jax.tree.map(
+            lambda a: a.reshape(natoms, *a.shape[2:]), out)
+    forces = assemble_forces(dedr, nbr_idx, ok, natoms * n_shards,
+                             axis_name=axis_name)
     if not with_energy:
         return None, None, forces
-    if energy_via_z:
-        z = bs.compute_zlist(ut, idx)
-        b = bs.compute_blist(ut, z, idx, cfg.bzero_flag)
-        e_atom = beta0 + b @ beta.astype(b.dtype)
-    else:
-        e_atom = energy_from_ylist(cfg, ut, y, beta, beta0)
     return jnp.sum(e_atom), e_atom, forces
 
 

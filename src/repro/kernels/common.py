@@ -18,9 +18,25 @@ from functools import lru_cache
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
 LANES = 128
 PI = 3.141592653589793
+# block indices are int32 even under jax_enable_x64: a Python-int 0 in an
+# index map traces as int64 there, which Mosaic cannot lower
+I0 = np.int32(0)
+
+
+def plane_spec(rows):
+    """Block of a ``[rows, natoms_pad]`` plane: lane tile ``i`` of the
+    (first) grid axis."""
+    return pl.BlockSpec((rows, LANES), lambda i, *_: (I0, i))
+
+
+def pair_spec(nnbor):
+    """Block of a ``[nnbor, 4, natoms_pad]`` per-pair array: lane tile
+    ``i``, every neighbor row."""
+    return pl.BlockSpec((nnbor, 4, LANES), lambda i: (I0, I0, i))
 
 
 @lru_cache(maxsize=8)
@@ -61,29 +77,59 @@ def level_consts(twojmax: int):
     return tuple(out)
 
 
+def iota(dtype, shape, dim):
+    """``broadcasted_iota`` in any dtype: Mosaic only builds integer iotas,
+    so count in int32 and cast (exact for the small counts used here)."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim).astype(dtype)
+
+
+def reverse(x, axis):
+    """``jnp.flip`` along one axis as a concatenation of static unit
+    slices: Mosaic has no lowering for ``rev``, and the reversed extents
+    here are at most twojmax + 1 rows."""
+    n = x.shape[axis]
+    return jnp.concatenate(
+        [jax.lax.slice_in_dim(x, i, i + 1, axis=axis)
+         for i in reversed(range(n))], axis=axis)
+
+
+def for_each_neighbor(nnbor: int, body):
+    """``body(k)`` for k in range(nnbor), as a rolled in-kernel loop.
+
+    Rolled, one neighbor's recursion state is live at a time and the
+    compile time does not grow with the neighbor count.  The bounds are
+    int32 because under ``jax_enable_x64`` Python-int bounds give an
+    int64 induction variable, which Mosaic cannot lower."""
+    def step(k, carry):
+        body(k)
+        return carry
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(nnbor), step, jnp.int32(0))
+
+
 def level_coefs(j: int, dtype):
     """In-kernel constant builders (Pallas forbids captured trace-time
     constants; iota arithmetic keeps the kernel self-contained).
 
     Returns CA, CB [rows, j, 1], SGN [nmir, j+1, 1], W [j+1, j+1, 1]."""
-    import jax
     rows = j // 2 + 1
     nmir = j + 1 - rows
-    ma = jax.lax.broadcasted_iota(dtype, (rows, j, 1), 1)
-    mb = jax.lax.broadcasted_iota(dtype, (rows, j, 1), 0)
+    ma = iota(dtype, (rows, j, 1), 1)
+    mb = iota(dtype, (rows, j, 1), 0)
     ca = jnp.sqrt((j - ma) / (j - mb))
     cb = -jnp.sqrt((ma + 1.0) / (j - mb))
-    r = jax.lax.broadcasted_iota(dtype, (nmir, j + 1, 1), 0)
-    c = jax.lax.broadcasted_iota(dtype, (nmir, j + 1, 1), 1)
+    r = iota(dtype, (nmir, j + 1, 1), 0)
+    c = iota(dtype, (nmir, j + 1, 1), 1)
     sgn = 1.0 - 2.0 * jnp.mod(r + rows + c, 2.0)
-    mbw = jax.lax.broadcasted_iota(dtype, (j + 1, j + 1, 1), 0)
-    maw = jax.lax.broadcasted_iota(dtype, (j + 1, j + 1, 1), 1)
-    half = jnp.asarray(j / 2.0, dtype)
+    mbw = iota(dtype, (j + 1, j + 1, 1), 0)
+    maw = iota(dtype, (j + 1, j + 1, 1), 1)
+    # typed branch values: two Python floats would make an f64 array
+    # under jax_enable_x64, which Mosaic cannot hold
+    half, one, zero = (jnp.asarray(v, dtype) for v in (j / 2.0, 1.0, 0.0))
     w = jnp.where(
-        mbw < half, 1.0,
-        jnp.where(mbw > half, 0.0,
-                  jnp.where(maw < half, 1.0,
-                            jnp.where(maw > half, 0.0, 0.5))))
+        mbw < half, one,
+        jnp.where(mbw > half, zero,
+                  jnp.where(maw < half, one,
+                            jnp.where(maw > half, zero, 0.5 * one))))
     return ca, cb, sgn, w
 
 
@@ -101,8 +147,8 @@ def u_level_step(prev_r, prev_i, a_r, a_i, b_r, b_i, j, dtype):
                                   conj_mul(b_r, b_i, p_r, p_i))
     # symmetry fill: u(j-mb, j-ma) -> sign * conj
     nmir = j + 1 - rows
-    src_r = jnp.flip(left_r[:nmir], axis=(0, 1))
-    src_i = jnp.flip(left_i[:nmir], axis=(0, 1))
+    src_r = reverse(reverse(left_r[:nmir], 0), 1)
+    src_i = reverse(reverse(left_i[:nmir], 0), 1)
     full_r = jnp.concatenate([left_r, sgn * src_r], axis=0)
     full_i = jnp.concatenate([left_i, -sgn * src_i], axis=0)
     return full_r, full_i
@@ -114,7 +160,7 @@ def mirror_row(row_r, row_i, j_prev, mbp, dtype):
     selected (row j_prev - mbp reversed by caller).  Applies the
     (-1)^(mb'+ma') conj transform."""
     cols = j_prev + 1
-    ma = jax.lax.broadcasted_iota(dtype, (cols, 1), 0)
+    ma = iota(dtype, (cols, 1), 0)
     sgn = 1.0 - 2.0 * jnp.mod(ma + mbp, 2.0)
     return sgn * row_r, -sgn * row_i
 
@@ -125,8 +171,8 @@ def half_prev_rows(left_r, left_i, j, dtype):
     if j % 2 == 1:
         return left_r, left_i
     jp = j - 1
-    src_r = jnp.flip(left_r[j // 2 - 1], axis=0)
-    src_i = jnp.flip(left_i[j // 2 - 1], axis=0)
+    src_r = reverse(left_r[j // 2 - 1], 0)
+    src_i = reverse(left_i[j // 2 - 1], 0)
     mr, mi = mirror_row(src_r, src_i, jp, j // 2, dtype)
     return (jnp.concatenate([left_r, mr[None]], axis=0),
             jnp.concatenate([left_i, mi[None]], axis=0))
@@ -238,6 +284,18 @@ def pad_lanes(arr, axis=-1, lanes=LANES):
 
 
 def default_interpret() -> bool:
-    """Pallas interpret mode unless running on a real TPU."""
-    import jax
-    return jax.devices()[0].platform != 'tpu'
+    """Pallas interpret mode on the CPU backend (the tests), compiled
+    Mosaic kernels on a TPU; any other platform is an error rather than a
+    silent fall back to the interpreter."""
+    platform = jax.devices()[0].platform
+    if platform == 'cpu':
+        return True
+    if platform == 'tpu':
+        return False
+    raise RuntimeError(f'SNAP Pallas kernels run on tpu (compiled) or cpu '
+                       f'(interpreted); found platform {platform!r}')
+
+
+def resolve_interpret(interpret):
+    """``interpret`` as given, or :func:`default_interpret` when None."""
+    return default_interpret() if interpret is None else bool(interpret)

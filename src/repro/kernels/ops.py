@@ -33,7 +33,7 @@ from repro.analysis.retrace import record_trace
 from repro.core.geometry import sanitize_displacements
 from repro.core.snap import SnapConfig, assemble_forces, bzero_shift
 
-from .common import LANES, default_interpret
+from .common import LANES
 from .snap_fused_de import snap_fused_de_pallas
 from .snap_fused_de_half import snap_fused_de_half_pallas
 from .snap_u import snap_u_half_pallas, snap_u_pallas
@@ -128,8 +128,6 @@ def snap_force_pipeline(cfg: SnapConfig, beta, beta0, dx, dy, dz, nbr_idx,
         raise ValueError(
             "mxu_dtype is a half-layout feature (the full-plane Y kernel "
             "has no low-precision path); drop it or use layout='half'")
-    if interpret is None:
-        interpret = default_interpret()
     natoms = dx.shape[0]
     disp, ok, _ = _kernel_layout(cfg, dx, dy, dz, mask, dtype)
     geo = dict(twojmax=cfg.twojmax, rcut=cfg.rcut, rmin0=cfg.rmin0,
@@ -181,7 +179,6 @@ def make_sharded_force_fn(cfg: SnapConfig, beta, beta0, mesh, axis='data',
     The total energy is psum-reduced and replicated.
     """
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.snap import energy_forces
@@ -194,11 +191,11 @@ def make_sharded_force_fn(cfg: SnapConfig, beta, beta0, mesh, axis='data',
                                      shard=(axis, n_shards), **kw)
         return jax.lax.psum(e, axis), e_atom, f
 
-    # check_rep=False: pallas_call has no replication rule (jax#21577-style
+    # check_vma=False: pallas_call has no replication rule (jax#21577-style
     # workaround); correctness is covered by the sharded-parity tests
-    sm = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
-                   out_specs=(P(), P(axis), P(axis)), check_rep=False)
+    sm = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
+                       out_specs=(P(), P(axis), P(axis)), check_vma=False)
     return jax.jit(sm)
 
 
@@ -289,8 +286,6 @@ def snap_ui_kernel(cfg: SnapConfig, dx, dy, dz, mask, dtype=jnp.float32,
     (test/benchmark plumbing — the pipeline itself stays in half planes);
     layout='full' runs the v1 full-plane kernel.
     """
-    if interpret is None:
-        interpret = default_interpret()
     disp, ok, natoms = _kernel_layout(cfg, dx, dy, dz, mask, dtype)
     geo = dict(twojmax=cfg.twojmax, rcut=cfg.rcut, rmin0=cfg.rmin0,
                rfac0=cfg.rfac0, switch_flag=cfg.switch_flag,
@@ -317,8 +312,6 @@ def snap_yi_kernel(cfg: SnapConfig, ulisttot, beta, dtype=jnp.float32,
     the dropped weight-0 middle-row columns also read 0 — compare on the
     ``dedr_weight > 0`` support.
     """
-    if interpret is None:
-        interpret = default_interpret()
     if mxu_dtype is not None and layout != 'half':
         raise ValueError("mxu_dtype requires layout='half'")
     idx = cfg.index
@@ -350,8 +343,6 @@ def snap_dedr_kernel(cfg: SnapConfig, dx, dy, dz, mask, ylist,
     the native half-plane kernel (half recursion state AND half Y
     streams); 'full' is the v1 kernel mirroring every level.
     """
-    if interpret is None:
-        interpret = default_interpret()
     idx = cfg.index
     disp, ok, natoms = _kernel_layout(cfg, dx, dy, dz, mask, dtype)
     pad = disp.shape[-1] - natoms

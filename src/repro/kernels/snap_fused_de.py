@@ -14,7 +14,7 @@ Fuses compute_dU with the force contraction (eq. 8): for every
    out comes dE/dr per pair.  This is the paper's headline memory win
    (0.1 GB / 0.9 GB total footprints).
 
-Layout identical to snap_u: atoms on lanes, neighbors unrolled.
+Layout identical to snap_u: atoms on lanes, neighbors in a rolled loop.
 """
 
 from __future__ import annotations
@@ -26,7 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.indices import build_index
-from .common import LANES, geom_ck_grad, level_coefs
+from .common import (LANES, for_each_neighbor, geom_ck_grad, level_coefs,
+                     pair_spec, plane_spec, resolve_interpret, reverse)
 
 
 def _dual_level_step(pr, pi, dpr, dpi, a, da, b, db, j, dtype):
@@ -54,9 +55,9 @@ def _dual_level_step(pr, pi, dpr, dpi, a, da, b, db, j, dtype):
     left_r = jnp.pad(ca * au_r, pad_a) + jnp.pad(cb * bu_r, pad_b)
     left_i = jnp.pad(ca * au_i, pad_a) + jnp.pad(cb * bu_i, pad_b)
     full_r = jnp.concatenate(
-        [left_r, sgn * jnp.flip(left_r[:nmir], axis=(0, 1))], axis=0)
+        [left_r, sgn * reverse(reverse(left_r[:nmir], 0), 1)], axis=0)
     full_i = jnp.concatenate(
-        [left_i, -sgn * jnp.flip(left_i[:nmir], axis=(0, 1))], axis=0)
+        [left_i, -sgn * reverse(reverse(left_i[:nmir], 0), 1)], axis=0)
 
     dfull_r, dfull_i = [], []
     for k in range(3):
@@ -69,9 +70,9 @@ def _dual_level_step(pr, pi, dpr, dpi, a, da, b, db, j, dtype):
         dl_r = jnp.pad(ca * dau_r, pad_a) + jnp.pad(cb * dbu_r, pad_b)
         dl_i = jnp.pad(ca * dau_i, pad_a) + jnp.pad(cb * dbu_i, pad_b)
         dfull_r.append(jnp.concatenate(
-            [dl_r, sgn * jnp.flip(dl_r[:nmir], axis=(0, 1))], axis=0))
+            [dl_r, sgn * reverse(reverse(dl_r[:nmir], 0), 1)], axis=0))
         dfull_i.append(jnp.concatenate(
-            [dl_i, -sgn * jnp.flip(dl_i[:nmir], axis=(0, 1))], axis=0))
+            [dl_i, -sgn * reverse(reverse(dl_i[:nmir], 0), 1)], axis=0))
     return full_r, full_i, dfull_r, dfull_i
 
 
@@ -81,7 +82,7 @@ def _fused_de_kernel(disp_ref, y_r_ref, y_i_ref, out_ref, *, twojmax, nnbor,
     out_ref: [nnbor, 4, LANES] = dE/d(x,y,z) per pair (row 3 zero)."""
     idx = build_index(twojmax)
 
-    for k in range(nnbor):
+    def neighbor(k):
         x = disp_ref[k, 0, :]
         y = disp_ref[k, 1, :]
         z = disp_ref[k, 2, :]
@@ -129,9 +130,11 @@ def _fused_de_kernel(disp_ref, y_r_ref, y_i_ref, out_ref, *, twojmax, nnbor,
             out_ref[k, d, :] = 2.0 * acc[d]
         out_ref[k, 3, :] = jnp.zeros((LANES,), dtype)
 
+    for_each_neighbor(nnbor, neighbor)
+
 
 def snap_fused_de_pallas(disp, y_r, y_i, *, twojmax, rcut, rmin0=0.0,
-                         rfac0=0.99363, switch_flag=True, interpret=True):
+                         rfac0=0.99363, switch_flag=True, interpret=None):
     """disp: [nnbor, 4, natoms_pad]; y_r/y_i: [idxu_max, natoms_pad].
 
     Returns dedr: [nnbor, 4, natoms_pad] (rows x, y, z, 0).
@@ -148,10 +151,9 @@ def snap_fused_de_pallas(disp, y_r, y_i, *, twojmax, rcut, rmin0=0.0,
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((nnbor, 4, LANES), lambda i: (0, 0, i)),
-                  pl.BlockSpec((idx.idxu_max, LANES), lambda i: (0, i)),
-                  pl.BlockSpec((idx.idxu_max, LANES), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((nnbor, 4, LANES), lambda i: (0, 0, i)),
+        in_specs=[pair_spec(nnbor), plane_spec(idx.idxu_max),
+                  plane_spec(idx.idxu_max)],
+        out_specs=pair_spec(nnbor),
         out_shape=jax.ShapeDtypeStruct((nnbor, 4, natoms_pad), dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(disp, y_r, y_i)

@@ -30,8 +30,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.indices import build_index
-from .common import (LANES, conj_mul, geom_ck_grad, half_prev_rows,
-                     level_coefs, level_stitch)
+from .common import (LANES, conj_mul, for_each_neighbor, geom_ck_grad,
+                     half_prev_rows, level_coefs, level_stitch, pair_spec,
+                     plane_spec, resolve_interpret)
 
 
 def _cm_add(x, y):
@@ -74,7 +75,7 @@ def _fused_de_half_kernel(disp_ref, y_r_ref, y_i_ref, out_ref, *, twojmax,
                           nnbor, rcut, rmin0, rfac0, switch_flag, dtype):
     idx = build_index(twojmax)
 
-    for k in range(nnbor):
+    def neighbor(k):
         x = disp_ref[k, 0, :]
         y = disp_ref[k, 1, :]
         z = disp_ref[k, 2, :]
@@ -123,10 +124,12 @@ def _fused_de_half_kernel(disp_ref, y_r_ref, y_i_ref, out_ref, *, twojmax,
             out_ref[k, d, :] = 2.0 * acc[d]
         out_ref[k, 3, :] = jnp.zeros((LANES,), dtype)
 
+    for_each_neighbor(nnbor, neighbor)
+
 
 def snap_fused_de_half_pallas(disp, y_r, y_i, *, twojmax, rcut, rmin0=0.0,
                               rfac0=0.99363, switch_flag=True,
-                              interpret=True):
+                              interpret=None):
     """Same contract as snap_fused_de_pallas, except ``y_r``/``y_i`` are
     **half planes** ``[idxu_half_max, natoms_pad]`` (the native output of
     the half-plane Y kernel); recursion state is half-plane throughout."""
@@ -143,10 +146,8 @@ def snap_fused_de_half_pallas(disp, y_r, y_i, *, twojmax, rcut, rmin0=0.0,
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((nnbor, 4, LANES), lambda i: (0, 0, i)),
-                  pl.BlockSpec((nh, LANES), lambda i: (0, i)),
-                  pl.BlockSpec((nh, LANES), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((nnbor, 4, LANES), lambda i: (0, 0, i)),
+        in_specs=[pair_spec(nnbor), plane_spec(nh), plane_spec(nh)],
+        out_specs=pair_spec(nnbor),
         out_shape=jax.ShapeDtypeStruct((nnbor, 4, natoms_pad), dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(disp, y_r, y_i)

@@ -3,8 +3,8 @@
 Adaptation of the paper's shared-memory recursion kernel:
 
 - one grid step owns a 128-atom lane tile (AoSoA inner "A" = lane width);
-- the neighbor sum that needed CUDA atomics becomes an in-register
-  reduction over the neighbor axis (statically unrolled);
+- the neighbor sum that needed CUDA atomics becomes a reduction over the
+  neighbor axis into the VMEM output block (a rolled in-kernel loop);
 - only the previous recursion level is kept live (the paper's double
   buffer) — the full Ulist per pair is never materialized in HBM, only the
   per-atom Ulisttot leaves the kernel;
@@ -32,17 +32,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.indices import build_index
-from .common import LANES, geom_ck, u_half_level_step, u_level_step
+from .common import (LANES, for_each_neighbor, geom_ck, pair_spec, plane_spec,
+                     resolve_interpret, u_half_level_step, u_level_step)
 
 
-def _snap_u_kernel(disp_ref, out_r_ref, out_i_ref, *, twojmax, nnbor,
-                   rcut, rmin0, rfac0, switch_flag, dtype):
+def _snap_u_kernel(disp_ref, out_r_ref, out_i_ref, *, level_step, blocks,
+                   twojmax, nnbor, rcut, rmin0, rfac0, switch_flag, dtype):
     """disp_ref: [nnbor, 4, LANES] rows (x, y, z, mask) — atoms on lanes.
-    out_*_ref: [idxu_max, LANES] accumulated sum_k sfac_k * U_k (no self)."""
-    idx = build_index(twojmax)
-    acc_r = jnp.zeros((idx.idxu_max, LANES), dtype)
-    acc_i = jnp.zeros((idx.idxu_max, LANES), dtype)
-    for k in range(nnbor):
+    out_*_ref: [rows, LANES] accumulated sum_k sfac_k * U_k (no self).
+
+    ``level_step`` advances the recursion one level (full planes or left
+    rows only) and ``blocks[j]`` is level j's first output row.  Each
+    level is added into its row block of the output refs as soon as it
+    exists, so only one neighbor's recursion state is ever live (holding
+    all levels of all neighbors overruns Mosaic's 16 MiB scoped VMEM at
+    2J=14)."""
+    out_r_ref[...] = jnp.zeros(out_r_ref.shape, dtype)
+    out_i_ref[...] = jnp.zeros(out_i_ref.shape, dtype)
+
+    def neighbor(k):
         x = disp_ref[k, 0, :]
         y = disp_ref[k, 1, :]
         z = disp_ref[k, 2, :]
@@ -50,104 +58,61 @@ def _snap_u_kernel(disp_ref, out_r_ref, out_i_ref, *, twojmax, nnbor,
         a_r, a_i, b_r, b_i, sfac = geom_ck(
             x, y, z, rcut, rmin0, rfac0, switch_flag)
         sfac = sfac * m
+        out_r_ref[0:1, :] += sfac[None, :]
         lvl_r = jnp.ones((1, 1, LANES), dtype)
         lvl_i = jnp.zeros((1, 1, LANES), dtype)
-        outs_r = [sfac[None, :]]
-        outs_i = [jnp.zeros((1, LANES), dtype)]
         for j in range(1, twojmax + 1):
-            lvl_r, lvl_i = u_level_step(
-                lvl_r, lvl_i, a_r, a_i, b_r, b_i, j, dtype)
-            n = (j + 1) ** 2
-            outs_r.append(sfac * lvl_r.reshape(n, LANES))
-            outs_i.append(sfac * lvl_i.reshape(n, LANES))
-        acc_r = acc_r + jnp.concatenate(outs_r, axis=0)
-        acc_i = acc_i + jnp.concatenate(outs_i, axis=0)
-    out_r_ref[...] = acc_r
-    out_i_ref[...] = acc_i
+            lvl_r, lvl_i = level_step(lvl_r, lvl_i, a_r, a_i, b_r, b_i, j,
+                                      dtype)
+            base = int(blocks[j])
+            n = lvl_r.shape[0] * lvl_r.shape[1]
+            out_r_ref[base:base + n, :] += sfac * lvl_r.reshape(n, LANES)
+            out_i_ref[base:base + n, :] += sfac * lvl_i.reshape(n, LANES)
+
+    for_each_neighbor(nnbor, neighbor)
+
+
+def _u_call(disp, rows, level_step, blocks, twojmax, rcut, rmin0, rfac0,
+            switch_flag, interpret):
+    nnbor, four, natoms_pad = disp.shape
+    assert four == 4 and natoms_pad % LANES == 0
+    dtype = disp.dtype
+    kernel = partial(
+        _snap_u_kernel, level_step=level_step, blocks=blocks,
+        twojmax=twojmax, nnbor=nnbor, rcut=rcut, rmin0=rmin0, rfac0=rfac0,
+        switch_flag=switch_flag, dtype=dtype)
+    plane = jax.ShapeDtypeStruct((rows, natoms_pad), dtype)
+    return pl.pallas_call(
+        kernel,
+        grid=(natoms_pad // LANES,),
+        in_specs=[pair_spec(nnbor)],
+        out_specs=[plane_spec(rows), plane_spec(rows)],
+        out_shape=[plane, plane],
+        interpret=resolve_interpret(interpret),
+    )(disp)
 
 
 def snap_u_pallas(disp, *, twojmax, rcut, rmin0=0.0, rfac0=0.99363,
-                  switch_flag=True, interpret=True):
+                  switch_flag=True, interpret=None):
     """disp: [nnbor, 4, natoms_pad] (x, y, z, mask), natoms_pad % 128 == 0.
 
     Returns (ut_r, ut_i): [idxu_max, natoms_pad], neighbor-accumulated raw
     U sums (self contribution NOT included — added by the ops wrapper).
     """
-    nnbor, four, natoms_pad = disp.shape
-    assert four == 4 and natoms_pad % LANES == 0
     idx = build_index(twojmax)
-    dtype = disp.dtype
-    kernel = partial(
-        _snap_u_kernel, twojmax=twojmax, nnbor=nnbor, rcut=rcut,
-        rmin0=rmin0, rfac0=rfac0, switch_flag=switch_flag, dtype=dtype)
-    grid = (natoms_pad // LANES,)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((nnbor, 4, LANES), lambda i: (0, 0, i))],
-        out_specs=[pl.BlockSpec((idx.idxu_max, LANES), lambda i: (0, i)),
-                   pl.BlockSpec((idx.idxu_max, LANES), lambda i: (0, i))],
-        out_shape=[jax.ShapeDtypeStruct((idx.idxu_max, natoms_pad), dtype),
-                   jax.ShapeDtypeStruct((idx.idxu_max, natoms_pad), dtype)],
-        interpret=interpret,
-    )(disp)
-
-
-def _snap_u_half_kernel(disp_ref, out_r_ref, out_i_ref, *, twojmax, nnbor,
-                        rcut, rmin0, rfac0, switch_flag, dtype):
-    """Half-plane variant: the recursion state is left-rows-only from the
-    start (no per-level mirror fill at all), and the accumulated output is
-    the compacted ``[idxu_half_max, LANES]`` plane."""
-    idx = build_index(twojmax)
-    acc_r = jnp.zeros((idx.idxu_half_max, LANES), dtype)
-    acc_i = jnp.zeros((idx.idxu_half_max, LANES), dtype)
-    for k in range(nnbor):
-        x = disp_ref[k, 0, :]
-        y = disp_ref[k, 1, :]
-        z = disp_ref[k, 2, :]
-        m = disp_ref[k, 3, :]
-        a_r, a_i, b_r, b_i, sfac = geom_ck(
-            x, y, z, rcut, rmin0, rfac0, switch_flag)
-        sfac = sfac * m
-        lvl_r = jnp.ones((1, 1, LANES), dtype)
-        lvl_i = jnp.zeros((1, 1, LANES), dtype)
-        outs_r = [sfac[None, :]]
-        outs_i = [jnp.zeros((1, LANES), dtype)]
-        for j in range(1, twojmax + 1):
-            lvl_r, lvl_i = u_half_level_step(
-                lvl_r, lvl_i, a_r, a_i, b_r, b_i, j, dtype)
-            n = (j // 2 + 1) * (j + 1)
-            outs_r.append(sfac * lvl_r.reshape(n, LANES))
-            outs_i.append(sfac * lvl_i.reshape(n, LANES))
-        acc_r = acc_r + jnp.concatenate(outs_r, axis=0)
-        acc_i = acc_i + jnp.concatenate(outs_i, axis=0)
-    out_r_ref[...] = acc_r
-    out_i_ref[...] = acc_i
+    return _u_call(disp, idx.idxu_max, u_level_step, idx.idxu_block,
+                   twojmax, rcut, rmin0, rfac0, switch_flag, interpret)
 
 
 def snap_u_half_pallas(disp, *, twojmax, rcut, rmin0=0.0, rfac0=0.99363,
-                       switch_flag=True, interpret=True):
+                       switch_flag=True, interpret=None):
     """Half-plane U: same contract as :func:`snap_u_pallas` but the output
     planes are ``[idxu_half_max, natoms_pad]`` — only the symmetric left
-    rows (2mb <= j) ever exist, in HBM or VMEM.  The mirrored rows are
-    recoverable through ``SnapIndex.full_to_half``; the downstream kernels
-    never need them materialized."""
-    nnbor, four, natoms_pad = disp.shape
-    assert four == 4 and natoms_pad % LANES == 0
+    rows (2mb <= j) ever exist, in HBM or VMEM: the recursion state is
+    left-rows-only from the start (no per-level mirror fill at all).  The
+    mirrored rows are recoverable through ``SnapIndex.full_to_half``; the
+    downstream kernels never need them materialized."""
     idx = build_index(twojmax)
-    dtype = disp.dtype
-    kernel = partial(
-        _snap_u_half_kernel, twojmax=twojmax, nnbor=nnbor, rcut=rcut,
-        rmin0=rmin0, rfac0=rfac0, switch_flag=switch_flag, dtype=dtype)
-    grid = (natoms_pad // LANES,)
-    nh = idx.idxu_half_max
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((nnbor, 4, LANES), lambda i: (0, 0, i))],
-        out_specs=[pl.BlockSpec((nh, LANES), lambda i: (0, i)),
-                   pl.BlockSpec((nh, LANES), lambda i: (0, i))],
-        out_shape=[jax.ShapeDtypeStruct((nh, natoms_pad), dtype),
-                   jax.ShapeDtypeStruct((nh, natoms_pad), dtype)],
-        interpret=interpret,
-    )(disp)
+    return _u_call(disp, idx.idxu_half_max, u_half_level_step,
+                   idx.idxu_half_block, twojmax, rcut, rmin0, rfac0,
+                   switch_flag, interpret)

@@ -18,7 +18,10 @@ beta-agnostic and Z is never materialized — the paper's compute_Yi fusion.
 Layout: atoms on the 128-wide lane axis ([idxu_max, natoms_pad] planes,
 identical to snap_u / snap_fused_de), grid = (lane tiles, COO tiles) with
 the partial-Y accumulator revisiting its VMEM block across the inner COO
-axis.  Index tables stream through VMEM one [1, tile] row at a time.
+axis.  Index tables are stored ``[ntiles, 1, tile]`` and stream through
+VMEM one ``[1, tile]`` row at a time: the unit middle axis makes each
+block span its array's full last two dimensions, which Mosaic requires of
+a block whose sublane extent (1) is not a multiple of 8.
 
 The **half-plane** variant (:func:`snap_y_half_pallas`) indexes the
 symmetric half space instead: U planes come in as ``[idxu_half_max, L]``
@@ -34,7 +37,7 @@ A ``mxu_dtype`` knob (default: the plane dtype) casts every operand
 feeding ``jnp.dot`` — one-hots and U planes on the gather side, the
 coefficient-scaled scatter one-hot and the Z products on the scatter
 side — while ``preferred_element_type`` keeps accumulation in the plane
-dtype.  ``mxu_dtype=jnp.bfloat16`` opens the MXU's native bf16 rate on
+dtype.  An f32 feed asks for full-precision (``HIGHEST``) MXU passes.  ``mxu_dtype=jnp.bfloat16`` opens the MXU's native bf16 rate on
 the one pipeline stage that is matmul-bound.
 """
 
@@ -46,17 +49,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.indices import build_index
 
-from .common import LANES
+from .common import I0, LANES, plane_spec, resolve_interpret
 
 Y_TILE = 512   # COO entries per grid step; 128-multiple keeps tiles aligned
+# scoped-VMEM limit of the Y kernels.  Mosaic's 16 MiB default is too small
+# for the full-plane kernel at 2J=14 (two [tile, 1240] one-hots plus the
+# f32 multi-pass matmul scratch); a v5e core has 128 MiB.
+Y_VMEM_LIMIT = 48 * 1024 * 1024
 
 
 @lru_cache(maxsize=16)
 def _y_coo_tiles(twojmax: int, tile: int):
-    """Static COO tables padded to [ntiles, tile] (pad rows carry cg = 0).
+    """Static COO tables padded to [ntiles, 1, tile] (pad rows: cg = 0).
 
     Returns (src1, src2, dest, cg, jjz): flat-u gather indices, flat-u
     scatter destination (idxz -> jju remap already applied), raw CG product,
@@ -68,13 +76,22 @@ def _y_coo_tiles(twojmax: int, tile: int):
     pad = ntiles * tile - nnz
 
     def p(a, dtype):
-        return np.pad(a, (0, pad)).astype(dtype).reshape(ntiles, tile)
+        return np.pad(a, (0, pad)).astype(dtype).reshape(ntiles, 1, tile)
 
     return (p(idx.z_coo_src1, np.int32),
             p(idx.z_coo_src2, np.int32),
             p(idx.idxz_jju[idx.z_coo_dest], np.int32),
             p(idx.z_coo_cg, np.float64),
             p(idx.z_coo_dest, np.int32))
+
+
+def _precision(mxu_dtype):
+    """Full-precision MXU passes for an f32 (or f64) feed, so the f32
+    pipeline keeps its f32 accuracy whatever Mosaic's default pass count
+    is; a bf16 feed is one native pass by construction."""
+    if jnp.dtype(mxu_dtype) == jnp.bfloat16:
+        return None
+    return jax.lax.Precision.HIGHEST
 
 
 def _snap_y_kernel(src1_ref, src2_ref, dest_ref, coef_ref, ut_r_ref, ut_i_ref,
@@ -96,25 +113,27 @@ def _snap_y_kernel(src1_ref, src2_ref, dest_ref, coef_ref, ut_r_ref, ut_i_ref,
 
     ut_r = ut_r_ref[...]
     ut_i = ut_i_ref[...]
-    u1r = jnp.dot(g1, ut_r, preferred_element_type=dtype)
-    u1i = jnp.dot(g1, ut_i, preferred_element_type=dtype)
-    u2r = jnp.dot(g2, ut_r, preferred_element_type=dtype)
-    u2i = jnp.dot(g2, ut_i, preferred_element_type=dtype)
+    dot = partial(jnp.dot, preferred_element_type=dtype,
+                  precision=_precision(dtype))
+    u1r = dot(g1, ut_r)
+    u1i = dot(g1, ut_i)
+    u2r = dot(g2, ut_r)
+    u2i = dot(g2, ut_i)
     prod_r = u1r * u2r - u1i * u2i
     prod_i = u1r * u2i + u1i * u2r
 
     iu_s = jax.lax.broadcasted_iota(jnp.int32, (idxu_max, tile), 0)
     s = ((dest_ref[0, :][None, :] == iu_s).astype(dtype)
          * coef_ref[0, :][None, :])
-    y_r_ref[...] += jnp.dot(s, prod_r, preferred_element_type=dtype)
-    y_i_ref[...] += jnp.dot(s, prod_i, preferred_element_type=dtype)
+    y_r_ref[...] += dot(s, prod_r)
+    y_i_ref[...] += dot(s, prod_i)
 
 
 def y_coef(beta, twojmax: int, tile: int = Y_TILE):
     """Runtime per-COO-entry coefficient ``cg * y_fac * beta[y_jjb]``.
 
     beta: [idxb_max] global linear-model coefficients.  Returns [ntiles,
-    tile] in beta's dtype — the only beta-dependent kernel input.
+    1, tile] in beta's dtype — the only beta-dependent kernel input.
     """
     idx = build_index(twojmax)
     _, _, _, cg, jjz = _y_coo_tiles(twojmax, tile)
@@ -124,9 +143,9 @@ def y_coef(beta, twojmax: int, tile: int = Y_TILE):
     return jnp.asarray(cg, beta.dtype) * betaj[..., jjz]
 
 
-def snap_y_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_TILE, interpret=True):
+def snap_y_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_TILE, interpret=None):
     """ut_r/ut_i: [idxu_max, natoms_pad] Ulisttot planes (self included);
-    coef: [ntiles, tile] from :func:`y_coef`.
+    coef: [ntiles, 1, tile] from :func:`y_coef`.
 
     Returns (y_r, y_i): [idxu_max, natoms_pad] adjoint planes, half-plane
     filled exactly like :func:`repro.core.bispectrum.compute_ylist`.
@@ -137,14 +156,14 @@ def snap_y_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_TILE, interpret=True):
     dtype = ut_r.dtype
     src1, src2, dest, _, _ = _y_coo_tiles(twojmax, tile)
     ntiles = src1.shape[0]
-    assert coef.shape == (ntiles, tile), (coef.shape, (ntiles, tile))
+    assert coef.shape == src1.shape, (coef.shape, src1.shape)
     coef = coef.astype(dtype)
 
     kernel = partial(_snap_y_kernel, idxu_max=idx.idxu_max, tile=tile,
                      dtype=dtype)
     grid = (natoms_pad // LANES, ntiles)
-    coo_spec = pl.BlockSpec((1, tile), lambda i, t: (t, 0))
-    u_spec = pl.BlockSpec((idx.idxu_max, LANES), lambda i, t: (0, i))
+    coo_spec = pl.BlockSpec((None, 1, tile), lambda i, t: (t, I0, I0))
+    u_spec = plane_spec(idx.idxu_max)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -153,7 +172,8 @@ def snap_y_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_TILE, interpret=True):
         out_shape=[
             jax.ShapeDtypeStruct((idx.idxu_max, natoms_pad), dtype),
             jax.ShapeDtypeStruct((idx.idxu_max, natoms_pad), dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=Y_VMEM_LIMIT),
     )(jnp.asarray(src1), jnp.asarray(src2), jnp.asarray(dest), coef,
       ut_r, ut_i)
 
@@ -164,7 +184,7 @@ def snap_y_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_TILE, interpret=True):
 
 @lru_cache(maxsize=16)
 def _y_half_coo_tiles(twojmax: int, tile: int):
-    """Half-space COO tables padded to [ntiles, tile] (pad rows: cg = 0).
+    """Half-space COO tables padded to [ntiles, 1, tile] (pad: cg = 0).
 
     Returns (src1, src2, sig1, sig2, dest, cg, jjz): half-space gather
     indices, ±1 conjugation factors for the imaginary gathers, half-space
@@ -178,7 +198,7 @@ def _y_half_coo_tiles(twojmax: int, tile: int):
 
     def p(a, dtype, fill=0):
         return np.pad(a, (0, pad), constant_values=fill) \
-            .astype(dtype).reshape(ntiles, tile)
+            .astype(dtype).reshape(ntiles, 1, tile)
 
     return (p(idx.z_half_src1, np.int32),
             p(idx.z_half_src2, np.int32),
@@ -216,7 +236,8 @@ def _snap_y_half_kernel(src1_ref, src2_ref, sig1_ref, sig2_ref, dest_ref,
 
     ut_r = ut_r_ref[...].astype(mxu_dtype)
     ut_i = ut_i_ref[...].astype(mxu_dtype)
-    dot = partial(jnp.dot, preferred_element_type=dtype)
+    dot = partial(jnp.dot, preferred_element_type=dtype,
+                  precision=_precision(mxu_dtype))
     v1r = dot(g1, ut_r)
     v1i = dot(g1, ut_i) * sig1_ref[0, :][:, None]   # σ1 · Im(u_half[src1])
     v2r = dot(g2, ut_r)
@@ -242,9 +263,9 @@ def y_coef_half(beta, twojmax: int, tile: int = Y_TILE):
 
 
 def snap_y_half_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_TILE,
-                       mxu_dtype=None, interpret=True):
+                       mxu_dtype=None, interpret=None):
     """ut_r/ut_i: [idxu_half_max, natoms_pad] half Ulisttot planes (self
-    included); coef: [ntiles, tile] from :func:`y_coef_half`.
+    included); coef: [ntiles, 1, tile] from :func:`y_coef_half`.
 
     Returns (y_r, y_i): [idxu_half_max, natoms_pad] adjoint half planes —
     exactly the left rows of :func:`repro.core.bispectrum.compute_ylist`
@@ -261,15 +282,15 @@ def snap_y_half_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_TILE,
     mxu_dtype = jnp.dtype(mxu_dtype) if mxu_dtype is not None else dtype
     src1, src2, sig1, sig2, dest, _, _ = _y_half_coo_tiles(twojmax, tile)
     ntiles = src1.shape[0]
-    assert coef.shape == (ntiles, tile), (coef.shape, (ntiles, tile))
+    assert coef.shape == src1.shape, (coef.shape, src1.shape)
     coef = coef.astype(dtype)
 
     kernel = partial(_snap_y_half_kernel, idxu_half_max=idx.idxu_half_max,
                      tile=tile, dtype=dtype, mxu_dtype=mxu_dtype)
     grid = (natoms_pad // LANES, ntiles)
     nh = idx.idxu_half_max
-    coo_spec = pl.BlockSpec((1, tile), lambda i, t: (t, 0))
-    u_spec = pl.BlockSpec((nh, LANES), lambda i, t: (0, i))
+    coo_spec = pl.BlockSpec((None, 1, tile), lambda i, t: (t, I0, I0))
+    u_spec = plane_spec(nh)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -279,7 +300,8 @@ def snap_y_half_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_TILE,
         out_shape=[
             jax.ShapeDtypeStruct((nh, natoms_pad), dtype),
             jax.ShapeDtypeStruct((nh, natoms_pad), dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=Y_VMEM_LIMIT),
     )(jnp.asarray(src1), jnp.asarray(src2),
       jnp.asarray(sig1, dtype), jnp.asarray(sig2, dtype),
       jnp.asarray(dest), coef, ut_r, ut_i)

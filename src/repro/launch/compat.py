@@ -1,10 +1,4 @@
-"""Version-compat shims for jax mesh APIs.
-
-``axis_types=`` on :func:`jax.make_mesh` and :func:`jax.sharding.set_mesh`
-appeared after the 0.4.x line; on older jax the mesh itself is the context
-manager and all axes are implicitly Auto.  Centralizing the guards here
-keeps every launch/test call site version-agnostic.
-"""
+"""Mesh helpers shared by the launch code and the tests."""
 
 from __future__ import annotations
 
@@ -12,17 +6,11 @@ import jax
 
 
 def make_auto_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where supported."""
-    if hasattr(jax.sharding, 'AxisType'):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis of type Auto."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def set_mesh(mesh):
     """Context manager installing ``mesh`` as the ambient mesh."""
-    sm = getattr(jax.sharding, 'set_mesh', None)
-    if sm is not None:
-        return sm(mesh)
-    return mesh   # jax <= 0.4.x: Mesh is itself a context manager
+    return jax.sharding.set_mesh(mesh)

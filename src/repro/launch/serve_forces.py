@@ -31,11 +31,13 @@ Robustness contract (layered on PR 6's recovery primitives):
   their deadline or the retry budget runs out; expired requests fail
   with :class:`~repro.launch.request_queue.DeadlineExceededError` before
   touching the device.
-- **Graceful degradation**: a kernel-path fault (an exception out of the
-  compiled kernel entry, incl. injected
+- **Graceful degradation**: a kernel-path fault (an exception out of
+  running the bucket's already-compiled kernel entry, incl. injected
   :class:`~repro.md.fault_inject.KernelPathFault`) re-runs the step on
   the jnp reference path; after ``quarantine_after`` strikes the bucket
   is quarantined to the reference path permanently — slower, never down.
+  Compiling a bucket happens outside that handling, so a compile or
+  lowering error propagates to the caller instead of degrading.
 - **Durability** (DESIGN.md "Durability contract"): with a
   :class:`~repro.launch.journal.Journal` attached, every admitted
   request is journaled ``accepted`` (payload included) before
@@ -351,16 +353,19 @@ class ForceServer:
 
     # -- dispatch ----------------------------------------------------------
 
-    def _fn(self, bucket: Bucket, impl: str) -> Callable:
+    def _fn(self, bucket: Bucket, impl: str, arrays: Dict) -> Callable:
+        """The bucket's compiled step, compiled (ahead of time, from the
+        static bucket shapes of ``arrays``) on first use."""
         key = (bucket, impl)
         if key not in self._fns:
             cfg = SnapConfig(twojmax=bucket.twojmax, rcut=bucket.rcut)
             counter = self._trace_counts.setdefault(
                 (bucket.key, impl), {})
-            self._fns[key] = make_batched_force_fn(
+            fn = make_batched_force_fn(
                 cfg, bucket.n_pad, bucket.max_nbors, impl=IMPLS[impl],
                 dtype=self.dtype, interpret=self.interpret,
                 trace_counter=counter, **self.force_kwargs)
+            self._fns[key] = fn.lower(**arrays).compile()
         return self._fns[key]
 
     def _pack(self, bucket: Bucket, live: List[QueueEntry]) -> Dict:
@@ -433,10 +438,11 @@ class ForceServer:
                 impl = 'jnp'
                 self._degraded_steps += 1
         if impl == 'kernel':
+            fn = self._fn(bucket, impl, arrays)   # compile errors propagate
             try:
-                out = self._fn(bucket, impl)(**arrays)
-                out = jax.block_until_ready(out)
+                out = jax.block_until_ready(fn(**arrays))
             except Exception:
+                # a runtime fault of the compiled kernel entry
                 self._strike(bucket)
                 impl = 'jnp'
                 self._degraded_steps += 1
@@ -444,7 +450,8 @@ class ForceServer:
         else:
             out = None
         if out is None:
-            out = jax.block_until_ready(self._fn(bucket, 'jnp')(**arrays))
+            out = jax.block_until_ready(
+                self._fn(bucket, 'jnp', arrays)(**arrays))
         e_b, f_b, flags_b = (np.asarray(out[0]), np.asarray(out[1]),
                              np.asarray(out[2]))
 

@@ -42,15 +42,14 @@ def make_dp_compressed_grad(loss_fn, mesh, axis: str = 'pod'):
     with the gradient reduction quantized to int8 over ``axis``.
     """
     from functools import partial
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(), P(axis)),
              out_specs=(P(), P()),
-             check_rep=False)
+             check_vma=False)
     def fn(params, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         loss = jax.lax.pmean(loss, axis)

@@ -245,6 +245,18 @@ def test_kernel_fault_quarantines_bucket_but_keeps_serving():
     assert [f['kind'] for f in inj.fired] == ['kernel_fault'] * 2
 
 
+def test_kernel_compile_error_propagates():
+    """A bucket whose kernel entry cannot be built fails loudly: the error
+    reaches the caller instead of degrading the step to the jnp path."""
+    srv = ForceServer(TABLE, impl='kernel', interpret=True, queue_depth=8,
+                      force_kwargs=dict(layout='no-such-layout'))
+    srv.submit(make_req('c0'), now=0.0)
+    with pytest.raises(ValueError, match='unknown layout'):
+        srv.step(0.0, **FROZEN)
+    h = srv.health()
+    assert h.degraded_steps == 0 and not h.kernel_faults, h
+
+
 # ---------------------------------------------------------------------------
 # open-loop driver + fault plan determinism
 # ---------------------------------------------------------------------------

@@ -40,6 +40,33 @@ def test_pipelines_agree(twojmax):
     np.testing.assert_allclose(f_b, f_g, atol=1e-11 * scale)
 
 
+@pytest.mark.parametrize('atom_chunks', [2, 4])
+@pytest.mark.parametrize('energy_via_z', [False, True])
+def test_adjoint_atom_chunks_match_one_block(cfg_2j4, atom_chunks,
+                                             energy_via_z):
+    """Running the per-atom stages block by block changes nothing but the
+    memory held at once: forces, per-atom and total energy match."""
+    _, disp, nbr_idx, mask, _ = make_cluster(seed=5)
+    beta = jnp.asarray(np.random.default_rng(4).normal(
+        size=cfg_2j4.ncoeff))
+    args = (cfg_2j4, beta, 0.3, disp[..., 0], disp[..., 1], disp[..., 2],
+            nbr_idx, mask)
+    e1, ea1, f1 = energy_forces_adjoint(*args, energy_via_z=energy_via_z)
+    ek, eak, fk = energy_forces_adjoint(*args, energy_via_z=energy_via_z,
+                                        atom_chunks=atom_chunks)
+    np.testing.assert_allclose(ek, e1, rtol=1e-13)
+    np.testing.assert_allclose(eak, ea1, rtol=1e-13, atol=1e-13)
+    np.testing.assert_allclose(fk, f1, rtol=1e-12, atol=1e-13)
+
+
+def test_adjoint_atom_chunks_must_divide_natoms(cfg_2j4):
+    _, disp, nbr_idx, mask, _ = make_cluster(seed=5)
+    beta = jnp.zeros(cfg_2j4.ncoeff)
+    with pytest.raises(ValueError, match='must divide'):
+        energy_forces_adjoint(cfg_2j4, beta, 0.0, disp[..., 0], disp[..., 1],
+                              disp[..., 2], nbr_idx, mask, atom_chunks=3)
+
+
 def test_energy_from_y_matches_z_path(cfg_2j8):
     """The (2/3) U*.Y energy identity vs the canonical Z->B path."""
     cfg = cfg_2j8
