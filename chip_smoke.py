@@ -99,7 +99,7 @@ def oracle_forces(cfg, beta, disp, nbr_idx, mask):
     return float(e), f
 
 
-def phase_force(config, mxu_bf16=False, natoms=None, interpret=False):
+def phase_force(config, mxu_bf16=False):
     """One compiled kernel force call against the f64 oracle."""
     import jax
     import jax.numpy as jnp
@@ -107,8 +107,7 @@ def phase_force(config, mxu_bf16=False, natoms=None, interpret=False):
 
     from repro.core.snap import energy_forces
 
-    spec, cfg, _, _, nbr_idx, mask, disp, beta = paper_problem(config,
-                                                               natoms)
+    spec, cfg, _, _, nbr_idx, mask, disp, beta = paper_problem(config)
     check(int(mask.sum(1).min()) == int(mask.sum(1).max()) == spec['nnbor'],
           f'{config}: expected {spec["nnbor"]} neighbours per atom')
     e_ref, f_ref = oracle_forces(cfg, beta, disp, nbr_idx, mask)
@@ -119,15 +118,14 @@ def phase_force(config, mxu_bf16=False, natoms=None, interpret=False):
             jnp.asarray(nbr_idx), jnp.asarray(mask))
     kw = dict(mxu_dtype=jnp.bfloat16) if mxu_bf16 else {}
     fn = jax.jit(lambda b, dx, dy, dz, ni, m: energy_forces(
-        cfg, b, 0.0, dx, dy, dz, ni, m, impl='kernel', interpret=interpret,
+        cfg, b, 0.0, dx, dy, dz, ni, m, impl='kernel', interpret=False,
         **kw))
     t0 = time.perf_counter()
     compiled = fn.lower(*args).compile()
     t_compile = time.perf_counter() - t0
     kernels = compiled.as_text().count('custom_call_target="tpu_custom_call"')
-    if not interpret:
-        check(kernels == 3, f'{config}: {kernels} Mosaic kernels in the '
-                            'compiled program, expected U, Y and dE')
+    check(kernels == 3, f'{config}: {kernels} Mosaic kernels in the '
+                        'compiled program, expected U, Y and dE')
     t0 = time.perf_counter()
     jax.block_until_ready(compiled(*args))
     t_first = time.perf_counter() - t0

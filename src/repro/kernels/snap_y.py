@@ -56,9 +56,10 @@ from repro.core.indices import build_index
 from .common import I0, LANES, plane_spec, resolve_interpret
 
 Y_TILE = 512   # COO entries per grid step; 128-multiple keeps tiles aligned
-# scoped-VMEM limit of the Y kernels.  Mosaic's 16 MiB default is too small
-# for the full-plane kernel at 2J=14 (two [tile, 1240] one-hots plus the
-# f32 multi-pass matmul scratch); a v5e core has 128 MiB.
+# scoped-VMEM limit of the full-plane Y kernel.  Mosaic's 16 MiB default is
+# too small for it at 2J=14 (two [tile, 1240] one-hots plus the f32
+# multi-pass matmul scratch); a v5e core has 128 MiB.  The half-plane kernel
+# fits the default.
 Y_VMEM_LIMIT = 48 * 1024 * 1024
 
 
@@ -301,7 +302,6 @@ def snap_y_half_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_TILE,
             jax.ShapeDtypeStruct((nh, natoms_pad), dtype),
             jax.ShapeDtypeStruct((nh, natoms_pad), dtype)],
         interpret=resolve_interpret(interpret),
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=Y_VMEM_LIMIT),
     )(jnp.asarray(src1), jnp.asarray(src2),
       jnp.asarray(sig1, dtype), jnp.asarray(sig2, dtype),
       jnp.asarray(dest), coef, ut_r, ut_i)

@@ -143,7 +143,7 @@ class ChaosReport:
 
 
 def run_chaos_soak(plan: ChaosPlan, workdir, table: Optional[BucketTable]
-                   = None, impl: str = 'kernel', interpret=True,
+                   = None, impl: str = 'kernel', interpret=None,
                    queue_depth: int = 12, quarantine_after: int = 2,
                    snapshot_every: int = 2,
                    timer: Callable[[], float] = time.perf_counter,

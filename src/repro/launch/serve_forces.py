@@ -438,7 +438,18 @@ class ForceServer:
                 impl = 'jnp'
                 self._degraded_steps += 1
         if impl == 'kernel':
-            fn = self._fn(bucket, impl, arrays)   # compile errors propagate
+            try:
+                fn = self._fn(bucket, impl, arrays)
+            except Exception as exc:
+                # a compile error propagates, never degrades; the batch has
+                # already left the queue, so its requests get their
+                # terminal outcome first (no ack is left without one)
+                for e in live:
+                    self._finish(e, RequestFailedError(
+                        'kernel entry failed to compile', dict(
+                            req_id=e.req.req_id, bucket=bucket.key,
+                            error=type(exc).__name__)), now)
+                raise
             try:
                 out = jax.block_until_ready(fn(**arrays))
             except Exception:

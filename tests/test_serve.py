@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core.snap import SnapConfig
+from repro.launch.journal import read_events, replay
 from repro.launch.request_queue import (BucketTable, DeadlineExceededError,
                                         ForceRequest, RequestFailedError,
                                         RequestRejectedError,
@@ -245,16 +246,27 @@ def test_kernel_fault_quarantines_bucket_but_keeps_serving():
     assert [f['kind'] for f in inj.fired] == ['kernel_fault'] * 2
 
 
-def test_kernel_compile_error_propagates():
+def test_kernel_compile_error_propagates(tmp_path):
     """A bucket whose kernel entry cannot be built fails loudly: the error
-    reaches the caller instead of degrading the step to the jnp path."""
+    reaches the caller instead of degrading the step to the jnp path, and
+    the dequeued request still gets its one terminal outcome."""
+    jp = tmp_path / 'journal.jsonl'
     srv = ForceServer(TABLE, impl='kernel', interpret=True, queue_depth=8,
-                      force_kwargs=dict(layout='no-such-layout'))
+                      force_kwargs=dict(layout='no-such-layout'),
+                      journal=str(jp))
     srv.submit(make_req('c0'), now=0.0)
     with pytest.raises(ValueError, match='unknown layout'):
         srv.step(0.0, **FROZEN)
     h = srv.health()
     assert h.degraded_steps == 0 and not h.kernel_faults, h
+    err = srv.result('c0')
+    assert isinstance(err, RequestFailedError), err
+    assert err.diagnostics['error'] == 'ValueError'
+    assert 'c0' not in srv._inflight and srv.queue.depth == 0
+    assert h.failed == 1 and h.served == 0
+    # the journal holds the ack's one terminal event: nothing to replay
+    rec = replay(read_events(jp)).records['c0']
+    assert rec.n_terminal == 1 and rec.terminal['ev'] == 'failed', rec
 
 
 # ---------------------------------------------------------------------------

@@ -21,7 +21,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.indices import build_index
 from repro.kernels.snap_fused_de_half import snap_fused_de_half_pallas
 from repro.kernels.snap_u import snap_u_half_pallas
-from repro.kernels.snap_y import Y_TILE, _y_half_coo_tiles, snap_y_half_pallas
+from repro.kernels.snap_y import (Y_TILE, _y_coo_tiles, _y_half_coo_tiles,
+                                  snap_y_half_pallas, snap_y_pallas)
 
 NATOMS_PAD, NNBOR = 2048, 26
 GEO = dict(rcut=4.7, rmin0=0.0, rfac0=0.99363, switch_flag=True,
@@ -63,7 +64,8 @@ def compile_for(one_chip, fn, *shapes):
 
 
 def kernel_case(name, twojmax):
-    """(fn, shapes) of one half-layout kernel at the paper's sizes."""
+    """(fn, shapes) of one kernel at the paper's sizes: half layout, except
+    ``y_full`` (the full-plane A/B Y kernel)."""
     f32 = jnp.float32
     nh = build_index(twojmax).idxu_half_max
     disp = ((NNBOR, 4, NATOMS_PAD), f32)
@@ -74,6 +76,12 @@ def kernel_case(name, twojmax):
     if name == 'de':
         return (lambda d, yr, yi: snap_fused_de_half_pallas(
             d, yr, yi, twojmax=twojmax, **GEO), [disp, plane, plane])
+    if name == 'y_full':
+        nu = build_index(twojmax).idxu_max
+        ntiles = _y_coo_tiles(twojmax, Y_TILE)[0].shape[0]
+        return (lambda ur, ui, c: snap_y_pallas(
+            ur, ui, c, twojmax=twojmax, interpret=False),
+            [((nu, NATOMS_PAD), f32)] * 2 + [((ntiles, 1, Y_TILE), f32)])
     ntiles = _y_half_coo_tiles(twojmax, Y_TILE)[0].shape[0]
     mxu = jnp.bfloat16 if name == 'y_bf16' else None
     return (lambda ur, ui, c: snap_y_half_pallas(
@@ -86,6 +94,7 @@ def kernel_case(name, twojmax):
     ('u', 8), ('de', 8),
     ('u', 14),          # refused over the 16 MiB scoped-VMEM limit unrolled
     ('de', 14),
+    ('y_full', 14),     # needs its raised scoped-VMEM limit (Y_VMEM_LIMIT)
 ])
 def test_kernel_compiles_for_v5e(one_chip, name, twojmax):
     fn, shapes = kernel_case(name, twojmax)
