@@ -22,10 +22,19 @@ pipeline alive for A/B benchmarking (see benchmarks/b_kernels.py).
 ``mxu_dtype`` (half layout only) casts the Y kernel's matmul operands,
 e.g. ``jnp.bfloat16`` for the MXU's native low-precision rate with f32
 accumulation.
+
+Names in a profile: each kernel's ``pallas_call`` is named (``snap_u_half``,
+``snap_y_half``, ``snap_fused_de_half``; ``snap_u``, ``snap_y``,
+``snap_fused_de`` in the full layout), which names its custom-call
+instruction in the optimized HLO.  The glue between them runs under the
+named scopes ``snap.layout``, ``snap.self_planes``, ``snap.y_coef``,
+``snap.assemble`` and ``snap.energy``, which reach each op's ``op_name``
+(the profiler's ``tf_op``).
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -129,36 +138,43 @@ def snap_force_pipeline(cfg: SnapConfig, beta, beta0, dx, dy, dz, nbr_idx,
             "mxu_dtype is a half-layout feature (the full-plane Y kernel "
             "has no low-precision path); drop it or use layout='half'")
     natoms = dx.shape[0]
-    disp, ok, _ = _kernel_layout(cfg, dx, dy, dz, mask, dtype)
+    with jax.named_scope('snap.layout'):
+        disp, ok, _ = _kernel_layout(cfg, dx, dy, dz, mask, dtype)
     geo = dict(twojmax=cfg.twojmax, rcut=cfg.rcut, rmin0=cfg.rmin0,
                rfac0=cfg.rfac0, switch_flag=cfg.switch_flag,
                interpret=interpret)
 
     if layout == 'half':
         ut_r, ut_i = snap_u_half_pallas(disp, **geo)
-        ut_r = ut_r + _self_planes(cfg, dtype, 'half')   # elementwise
-        coef = y_coef_half(beta, cfg.twojmax, y_tile).astype(dtype)
+        with jax.named_scope('snap.self_planes'):
+            ut_r = ut_r + _self_planes(cfg, dtype, 'half')   # elementwise
+        with jax.named_scope('snap.y_coef'):
+            coef = y_coef_half(beta, cfg.twojmax, y_tile).astype(dtype)
         y_r, y_i = snap_y_half_pallas(ut_r, ut_i, coef, twojmax=cfg.twojmax,
                                       tile=y_tile, mxu_dtype=mxu_dtype,
                                       interpret=interpret)
         dedr = snap_fused_de_half_pallas(disp, y_r, y_i, **geo)
     else:
         ut_r, ut_i = snap_u_pallas(disp, **geo)
-        ut_r = ut_r + _self_planes(cfg, dtype)           # elementwise
-        coef = y_coef(beta, cfg.twojmax, y_tile).astype(dtype)
+        with jax.named_scope('snap.self_planes'):
+            ut_r = ut_r + _self_planes(cfg, dtype)           # elementwise
+        with jax.named_scope('snap.y_coef'):
+            coef = y_coef(beta, cfg.twojmax, y_tile).astype(dtype)
         y_r, y_i = snap_y_pallas(ut_r, ut_i, coef, twojmax=cfg.twojmax,
                                  tile=y_tile, interpret=interpret)
         dedr = snap_fused_de_pallas(disp, y_r, y_i, **geo)
 
     # pipeline exit: per-pair dE back to [natoms, nnbor, 3] force assembly
     axis_name, n_shards = shard if shard is not None else (None, 1)
-    dedr_pairs = dedr[:, :3, :natoms].transpose(2, 0, 1)
-    forces = assemble_forces(dedr_pairs, nbr_idx, ok, natoms * n_shards,
-                             axis_name=axis_name)
+    with jax.named_scope('snap.assemble'):
+        dedr_pairs = dedr[:, :3, :natoms].transpose(2, 0, 1)
+        forces = assemble_forces(dedr_pairs, nbr_idx, ok, natoms * n_shards,
+                                 axis_name=axis_name)
     if not with_energy:
         return None, None, forces
-    e_atom = energy_from_ylist_lanes(cfg, ut_r, ut_i, y_r, y_i,
-                                     beta, beta0)[:natoms]
+    with jax.named_scope('snap.energy'):
+        e_atom = energy_from_ylist_lanes(cfg, ut_r, ut_i, y_r, y_i,
+                                         beta, beta0)[:natoms]
     return jnp.sum(e_atom), e_atom, forces
 
 

@@ -156,4 +156,5 @@ def snap_fused_de_pallas(disp, y_r, y_i, *, twojmax, rcut, rmin0=0.0,
         out_specs=pair_spec(nnbor),
         out_shape=jax.ShapeDtypeStruct((nnbor, 4, natoms_pad), dtype),
         interpret=resolve_interpret(interpret),
+        name='snap_fused_de',
     )(disp, y_r, y_i)

@@ -150,4 +150,5 @@ def snap_fused_de_half_pallas(disp, y_r, y_i, *, twojmax, rcut, rmin0=0.0,
         out_specs=pair_spec(nnbor),
         out_shape=jax.ShapeDtypeStruct((nnbor, 4, natoms_pad), dtype),
         interpret=resolve_interpret(interpret),
+        name='snap_fused_de_half',
     )(disp, y_r, y_i)

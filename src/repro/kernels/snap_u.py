@@ -72,8 +72,8 @@ def _snap_u_kernel(disp_ref, out_r_ref, out_i_ref, *, level_step, blocks,
     for_each_neighbor(nnbor, neighbor)
 
 
-def _u_call(disp, rows, level_step, blocks, twojmax, rcut, rmin0, rfac0,
-            switch_flag, interpret):
+def _u_call(name, disp, rows, level_step, blocks, twojmax, rcut, rmin0,
+            rfac0, switch_flag, interpret):
     nnbor, four, natoms_pad = disp.shape
     assert four == 4 and natoms_pad % LANES == 0
     dtype = disp.dtype
@@ -89,6 +89,7 @@ def _u_call(disp, rows, level_step, blocks, twojmax, rcut, rmin0, rfac0,
         out_specs=[plane_spec(rows), plane_spec(rows)],
         out_shape=[plane, plane],
         interpret=resolve_interpret(interpret),
+        name=name,
     )(disp)
 
 
@@ -100,8 +101,9 @@ def snap_u_pallas(disp, *, twojmax, rcut, rmin0=0.0, rfac0=0.99363,
     U sums (self contribution NOT included — added by the ops wrapper).
     """
     idx = build_index(twojmax)
-    return _u_call(disp, idx.idxu_max, u_level_step, idx.idxu_block,
-                   twojmax, rcut, rmin0, rfac0, switch_flag, interpret)
+    return _u_call('snap_u', disp, idx.idxu_max, u_level_step,
+                   idx.idxu_block, twojmax, rcut, rmin0, rfac0, switch_flag,
+                   interpret)
 
 
 def snap_u_half_pallas(disp, *, twojmax, rcut, rmin0=0.0, rfac0=0.99363,
@@ -113,6 +115,6 @@ def snap_u_half_pallas(disp, *, twojmax, rcut, rmin0=0.0, rfac0=0.99363,
     mirrored rows are recoverable through ``SnapIndex.full_to_half``; the
     downstream kernels never need them materialized."""
     idx = build_index(twojmax)
-    return _u_call(disp, idx.idxu_half_max, u_half_level_step,
-                   idx.idxu_half_block, twojmax, rcut, rmin0, rfac0,
-                   switch_flag, interpret)
+    return _u_call('snap_u_half', disp, idx.idxu_half_max,
+                   u_half_level_step, idx.idxu_half_block, twojmax, rcut,
+                   rmin0, rfac0, switch_flag, interpret)

@@ -175,6 +175,7 @@ def snap_y_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_TILE, interpret=None):
             jax.ShapeDtypeStruct((idx.idxu_max, natoms_pad), dtype)],
         interpret=resolve_interpret(interpret),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=Y_VMEM_LIMIT),
+        name='snap_y',
     )(jnp.asarray(src1), jnp.asarray(src2), jnp.asarray(dest), coef,
       ut_r, ut_i)
 
@@ -302,6 +303,7 @@ def snap_y_half_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_TILE,
             jax.ShapeDtypeStruct((nh, natoms_pad), dtype),
             jax.ShapeDtypeStruct((nh, natoms_pad), dtype)],
         interpret=resolve_interpret(interpret),
+        name='snap_y_half',
     )(jnp.asarray(src1), jnp.asarray(src2),
       jnp.asarray(sig1, dtype), jnp.asarray(sig2, dtype),
       jnp.asarray(dest), coef, ut_r, ut_i)

@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.analysis.retrace import record_trace
 from repro.core.snap import SnapConfig, energy_forces
+from repro.runtime.trace import span
 from .cell_list import (FLAG_DRIFT, FLAG_ESCAPE, FLAG_NAN_FORCE,
                         FLAG_NAN_STATE, N_FLAGS, auto_cell_cap,
                         check_flags, device_neighbors, jitted_build,
@@ -132,6 +133,11 @@ def make_device_chunk_fn(cfg: SnapConfig, beta, beta0, dt, mass, grid,
     reference total energy (traced scalar; unused when the policy has no
     ``drift_tol``).
 
+    The step's XLA ops carry the named scopes ``md.verlet`` (half-kicks
+    and drift), ``md.cell_list`` (rebuild test and rebuild), ``md.pairs``
+    (pair displacements and the hard cut) and ``md.flags`` (guards),
+    which a profile shows as each op's ``tf_op``.
+
     force_fn: optional override for the force evaluation, e.g. an
     atom-sharded ``shard_map`` pipeline from
     :func:`repro.kernels.ops.make_sharded_force_fn`; signature
@@ -162,12 +168,9 @@ def make_device_chunk_fn(cfg: SnapConfig, beta, beta0, dt, mass, grid,
 
         def step(carry, _):
             pos, vel, f, nbr_idx, shifts, mask, pos_ref, flags = carry
-            vel = vel + (0.5 * dt * acc_scale) * f
-            pos = pos + dt * vel
-            moved2 = jnp.max(jnp.sum((pos - pos_ref) ** 2, axis=-1))
-            # skin=0 degenerates to rebuild-every-step (moved2 >= 0 always)
-            trigger = (moved2 > half_skin2) if grid.skin > 0 else (
-                moved2 >= 0.0)
+            with jax.named_scope('md.verlet'):
+                vel = vel + (0.5 * dt * acc_scale) * f
+                pos = pos + dt * vel
 
             def rebuild(_):
                 ni, ms, sh, fl = device_neighbors(pos, box, grid)
@@ -176,30 +179,38 @@ def make_device_chunk_fn(cfg: SnapConfig, beta, beta0, dt, mass, grid,
             def keep(_):
                 return nbr_idx, shifts, mask, pos_ref, flags, jnp.int32(0)
 
-            nbr_idx, shifts, mask, pos_ref, flags, rebuilt = jax.lax.cond(
-                trigger, rebuild, keep, None)
-            disp = pos[nbr_idx] + shifts - pos[:, None, :]
-            r2 = jnp.sum(disp * disp, axis=-1)
-            mask_t = mask & (r2 < rc2)              # exact per-step cutoff
+            with jax.named_scope('md.cell_list'):
+                moved2 = jnp.max(jnp.sum((pos - pos_ref) ** 2, axis=-1))
+                # skin=0 degenerates to rebuild-every-step (moved2 >= 0)
+                trigger = (moved2 > half_skin2) if grid.skin > 0 else (
+                    moved2 >= 0.0)
+                (nbr_idx, shifts, mask, pos_ref, flags,
+                 rebuilt) = jax.lax.cond(trigger, rebuild, keep, None)
+            with jax.named_scope('md.pairs'):
+                disp = pos[nbr_idx] + shifts - pos[:, None, :]
+                r2 = jnp.sum(disp * disp, axis=-1)
+                mask_t = mask & (r2 < rc2)          # exact per-step cutoff
             e, f_new = eval_force(disp, nbr_idx, mask_t)
-            vel = vel + (0.5 * dt * acc_scale) * f_new
-            ke = (0.5 * mass / ACC_CONV) * jnp.sum(vel * vel)
+            with jax.named_scope('md.verlet'):
+                vel = vel + (0.5 * dt * acc_scale) * f_new
+                ke = (0.5 * mass / ACC_CONV) * jnp.sum(vel * vel)
             if guards:
-                # sticky health lattice: cheap O(N) reductions vs the
-                # O(N*K*ncoeff) force pipeline, merged into the carried
-                # running-max vector (no extra host syncs)
-                bad_f = ~jnp.all(jnp.isfinite(f_new))
-                bad_s = ~(jnp.all(jnp.isfinite(pos))
-                          & jnp.all(jnp.isfinite(vel)))
-                esc = jnp.max(jnp.abs(pos / box - 0.5)) > escape_factor
-                health = [jnp.int32(0)] * N_FLAGS
-                health[FLAG_NAN_FORCE] = bad_f.astype(jnp.int32)
-                health[FLAG_NAN_STATE] = bad_s.astype(jnp.int32)
-                health[FLAG_ESCAPE] = esc.astype(jnp.int32)
-                if drift_tol is not None:
-                    drifted = jnp.abs((e + ke) - e_ref) > drift_tol
-                    health[FLAG_DRIFT] = drifted.astype(jnp.int32)
-                flags = jnp.maximum(flags, jnp.stack(health))
+                with jax.named_scope('md.flags'):
+                    # sticky health lattice: cheap O(N) reductions vs the
+                    # O(N*K*ncoeff) force pipeline, merged into the carried
+                    # running-max vector (no extra host syncs)
+                    bad_f = ~jnp.all(jnp.isfinite(f_new))
+                    bad_s = ~(jnp.all(jnp.isfinite(pos))
+                              & jnp.all(jnp.isfinite(vel)))
+                    esc = jnp.max(jnp.abs(pos / box - 0.5)) > escape_factor
+                    health = [jnp.int32(0)] * N_FLAGS
+                    health[FLAG_NAN_FORCE] = bad_f.astype(jnp.int32)
+                    health[FLAG_NAN_STATE] = bad_s.astype(jnp.int32)
+                    health[FLAG_ESCAPE] = esc.astype(jnp.int32)
+                    if drift_tol is not None:
+                        drifted = jnp.abs((e + ke) - e_ref) > drift_tol
+                        health[FLAG_DRIFT] = drifted.astype(jnp.int32)
+                    flags = jnp.maximum(flags, jnp.stack(health))
             carry = (pos, vel, f_new, nbr_idx, shifts, mask, pos_ref, flags)
             return carry, (e, ke, rebuilt)
 
@@ -287,11 +298,12 @@ def run_nve(cfg: SnapConfig, beta, beta0, state: MDState, n_steps: int,
             'policy/checkpoint/restore/fault_hook are device-loop '
             "features; use loop='device'")
     if loop == 'device':
-        return _run_nve_device(cfg, beta, beta0, state, n_steps, dt, mass,
-                               impl, max_nbors, log_every, force_kwargs,
-                               fn_cache, skin, cell_cap, shards, policy,
-                               checkpoint_dir, checkpoint_every, restore,
-                               fault_hook)
+        with span('md.run'):
+            return _run_nve_device(cfg, beta, beta0, state, n_steps, dt,
+                                   mass, impl, max_nbors, log_every,
+                                   force_kwargs, fn_cache, skin, cell_cap,
+                                   shards, policy, checkpoint_dir,
+                                   checkpoint_every, restore, fault_hook)
     if loop == 'scan':
         return _run_nve_scan(cfg, beta, beta0, state, n_steps, dt, mass,
                              impl, rebuild_every, max_nbors, log_every,
@@ -399,6 +411,14 @@ def _run_nve_device(cfg, beta, beta0, state, n_steps, dt, mass, impl,
     Because a chunk's outputs are only *committed* to the carry after a
     clean health check, a flagged chunk never contaminates the
     trajectory: rollback is simply "keep the previous carry".
+
+    The host's phases are spans of :mod:`repro.runtime.trace`: ``md.seed``
+    (seed build and force), then per chunk ``md.chunk`` around
+    ``md.hook`` (``fault_hook``), ``md.dispatch`` (the chunk call until it
+    returns), ``md.wait`` (the flag read-back, where the host blocks until
+    the chunk is done), ``md.log`` (rebuild count and thermo rows) and
+    ``md.recover`` (regrow or rollback, when taken); ``run_nve`` wraps
+    the whole call in ``md.run``.
     """
     from .resilience import (HealthReport, RecoveryEvent,
                              RecoveryExhaustedError, load_md_checkpoint,
@@ -472,39 +492,40 @@ def _run_nve_device(cfg, beta, beta0, state, n_steps, dt, mass, impl,
     regrows = 0
 
     if carry is None:
-        pos = jnp.asarray(state.pos)
-        vel = jnp.asarray(state.vel)
-        while True:   # seed build, with bounded regrow under a policy
-            nbr_idx, mask, shifts, fl = jitted_build(grid)(pos, boxj)
-            report = HealthReport.from_flags(fl, grid)
-            if not report.overflow:
-                break
-            if policy is None:
-                check_flags(fl, grid)   # raises the legacy typed error
-            if regrows >= max_regrows:
-                raise RecoveryExhaustedError(
-                    'initial neighbor build still overflows after '
-                    'regrowing', dict(step=state.step,
-                                      issues=report.issues(),
-                                      regrows=regrows))
-            new_grid = regrow_grid(grid, report, policy)
-            events.append(RecoveryEvent(
-                state.step, 'regrow',
-                dict(where='seed_build', issues=report.issues(),
-                     cell_cap=(grid.cell_cap, new_grid.cell_cap),
-                     max_nbors=(grid.max_nbors, new_grid.max_nbors))))
-            grid = cache['device_grid'] = new_grid
-            regrows += 1
-        # seed the force carry once at step 0 (exact rcut cut, like every
-        # step); jitted — an eager adjoint pipeline here would dominate
-        # short runs
-        e0, f = _seed_force(cache, cfg, beta, beta0, impl, kw, force_fn,
-                            pos, nbr_idx, shifts, mask)
-        ke0 = 0.5 * (mass / ACC_CONV) * float(jnp.sum(vel * vel))
-        e_ref = float(e0) + ke0
-        carry = dict(pos=pos, vel=vel, f=f, nbr_idx=nbr_idx,
-                     shifts=shifts, mask=mask, pos_ref=pos,
-                     flags=_full_flags(fl))
+        with span('md.seed'):
+            pos = jnp.asarray(state.pos)
+            vel = jnp.asarray(state.vel)
+            while True:   # seed build, with bounded regrow under a policy
+                nbr_idx, mask, shifts, fl = jitted_build(grid)(pos, boxj)
+                report = HealthReport.from_flags(fl, grid)
+                if not report.overflow:
+                    break
+                if policy is None:
+                    check_flags(fl, grid)   # raises the legacy typed error
+                if regrows >= max_regrows:
+                    raise RecoveryExhaustedError(
+                        'initial neighbor build still overflows after '
+                        'regrowing', dict(step=state.step,
+                                          issues=report.issues(),
+                                          regrows=regrows))
+                new_grid = regrow_grid(grid, report, policy)
+                events.append(RecoveryEvent(
+                    state.step, 'regrow',
+                    dict(where='seed_build', issues=report.issues(),
+                         cell_cap=(grid.cell_cap, new_grid.cell_cap),
+                         max_nbors=(grid.max_nbors, new_grid.max_nbors))))
+                grid = cache['device_grid'] = new_grid
+                regrows += 1
+            # seed the force carry once at step 0 (exact rcut cut, like every
+            # step); jitted — an eager adjoint pipeline here would dominate
+            # short runs
+            e0, f = _seed_force(cache, cfg, beta, beta0, impl, kw, force_fn,
+                                pos, nbr_idx, shifts, mask)
+            ke0 = 0.5 * (mass / ACC_CONV) * float(jnp.sum(vel * vel))
+            e_ref = float(e0) + ke0
+            carry = dict(pos=pos, vel=vel, f=f, nbr_idx=nbr_idx,
+                         shifts=shifts, mask=mask, pos_ref=pos,
+                         flags=_full_flags(fl))
 
     chunks = cache.setdefault('device_chunks', {})
     counter = cache.setdefault('device_trace_count', {})
@@ -515,86 +536,96 @@ def _run_nve_device(cfg, beta, beta0, state, n_steps, dt, mass, impl,
     steps_since_ckpt = 0
     chunk_len = max(1, min(log_every, n_steps))
     while it < n_steps:
-        n_sub = min(chunk_len, n_steps - it)
-        abs_step = state.step + it
-        # chunk fns are keyed by every static they bake in: length,
-        # grid capacities (regrows change array shapes), and dt
-        # (resilience may halve it) — at most one trace per key
-        key = (n_sub, grid.cell_cap, grid.max_nbors, dt_cur)
-        if key not in chunks:
-            chunks[key] = make_device_chunk_fn(
-                cfg, beta, beta0, dt_cur, mass, grid, impl, n_sub,
-                force_fn=force_fn, trace_counter=counter, policy=policy,
-                **kw)
-        attempt = carry
-        if fault_hook is not None:
-            attempt = fault_hook(abs_step, carry, grid)
-        (pos, vel, f, nbr_idx, shifts, mask, pos_ref, flags, pe, ke,
-         nreb) = chunks[key](attempt['pos'], attempt['vel'], attempt['f'],
-                             boxj, attempt['nbr_idx'], attempt['shifts'],
-                             attempt['mask'], attempt['pos_ref'],
-                             attempt['flags'], jnp.float64(e_ref))
-        # host boundary: health triage + logging rows, nothing else
-        if policy is None:
-            check_flags(flags, grid)
-        else:
-            report = HealthReport.from_flags(flags, grid)
-            if report.overflow:
-                if regrows >= max_regrows:
-                    raise RecoveryExhaustedError(
-                        'capacity overflows persisted past the regrow '
-                        'budget', dict(step=abs_step,
-                                       issues=report.issues(),
-                                       regrows=regrows))
-                new_grid = regrow_grid(grid, report, policy)
-                events.append(RecoveryEvent(
-                    abs_step, 'regrow',
-                    dict(issues=report.issues(),
-                         cell_cap=(grid.cell_cap, new_grid.cell_cap),
-                         max_nbors=(grid.max_nbors, new_grid.max_nbors))))
-                grid = cache['device_grid'] = new_grid
-                regrows += 1
-                # roll back to the last good chunk: rebuild the topology
-                # at the regrown capacities from the committed positions
-                # (the force carry is still valid — same positions)
-                ni, ms, sh, fl = jitted_build(grid)(carry['pos'], boxj)
-                carry = dict(carry, nbr_idx=ni, mask=ms, shifts=sh,
-                             pos_ref=carry['pos'],
-                             flags=_full_flags(fl))
+        with span('md.chunk'):
+            n_sub = min(chunk_len, n_steps - it)
+            abs_step = state.step + it
+            # chunk fns are keyed by every static they bake in: length,
+            # grid capacities (regrows change array shapes), and dt
+            # (resilience may halve it) — at most one trace per key
+            key = (n_sub, grid.cell_cap, grid.max_nbors, dt_cur)
+            if key not in chunks:
+                chunks[key] = make_device_chunk_fn(
+                    cfg, beta, beta0, dt_cur, mass, grid, impl, n_sub,
+                    force_fn=force_fn, trace_counter=counter,
+                    policy=policy, **kw)
+            attempt = carry
+            if fault_hook is not None:
+                with span('md.hook'):
+                    attempt = fault_hook(abs_step, carry, grid)
+            with span('md.dispatch'):
+                (pos, vel, f, nbr_idx, shifts, mask, pos_ref, flags, pe,
+                 ke, nreb) = chunks[key](
+                    attempt['pos'], attempt['vel'], attempt['f'], boxj,
+                    attempt['nbr_idx'], attempt['shifts'], attempt['mask'],
+                    attempt['pos_ref'], attempt['flags'],
+                    jnp.float64(e_ref))
+            # host boundary: health triage + logging rows, nothing else
+            with span('md.wait'):
+                if policy is None:
+                    check_flags(flags, grid)
+                else:
+                    report = HealthReport.from_flags(flags, grid)
+            if policy is not None and report.overflow:
+                with span('md.recover'):
+                    if regrows >= max_regrows:
+                        raise RecoveryExhaustedError(
+                            'capacity overflows persisted past the regrow '
+                            'budget', dict(step=abs_step,
+                                           issues=report.issues(),
+                                           regrows=regrows))
+                    new_grid = regrow_grid(grid, report, policy)
+                    events.append(RecoveryEvent(
+                        abs_step, 'regrow',
+                        dict(issues=report.issues(),
+                             cell_cap=(grid.cell_cap, new_grid.cell_cap),
+                             max_nbors=(grid.max_nbors,
+                                        new_grid.max_nbors))))
+                    grid = cache['device_grid'] = new_grid
+                    regrows += 1
+                    # roll back to the last good chunk: rebuild the
+                    # topology at the regrown capacities from the
+                    # committed positions (the force carry is still valid
+                    # — same positions)
+                    ni, ms, sh, fl = jitted_build(grid)(carry['pos'], boxj)
+                    carry = dict(carry, nbr_idx=ni, mask=ms, shifts=sh,
+                                 pos_ref=carry['pos'],
+                                 flags=_full_flags(fl))
                 continue
-            if report.numeric:
-                if numeric_retries >= policy.max_numeric_retries:
-                    raise report.numeric_error(
-                        dict(step=abs_step, issues=report.issues(),
-                             retries=numeric_retries, dt=dt_cur))
-                events.append(RecoveryEvent(
-                    abs_step, 'rollback',
-                    dict(issues=report.issues(),
-                         retries=numeric_retries)))
-                if numeric_retries >= policy.retries_before_dt_halve:
-                    dt_cur *= 0.5
-                    events.append(RecoveryEvent(abs_step, 'dt_halve',
-                                                dict(dt=dt_cur)))
-                numeric_retries += 1
+            if policy is not None and report.numeric:
+                with span('md.recover'):
+                    if numeric_retries >= policy.max_numeric_retries:
+                        raise report.numeric_error(
+                            dict(step=abs_step, issues=report.issues(),
+                                 retries=numeric_retries, dt=dt_cur))
+                    events.append(RecoveryEvent(
+                        abs_step, 'rollback',
+                        dict(issues=report.issues(),
+                             retries=numeric_retries)))
+                    if numeric_retries >= policy.retries_before_dt_halve:
+                        dt_cur *= 0.5
+                        events.append(RecoveryEvent(abs_step, 'dt_halve',
+                                                    dict(dt=dt_cur)))
+                    numeric_retries += 1
                 continue   # carry is still the last good chunk
-        # clean chunk: commit to the carry and the thermo log
-        carry = dict(pos=pos, vel=vel, f=f, nbr_idx=nbr_idx,
-                     shifts=shifts, mask=mask, pos_ref=pos_ref,
-                     flags=flags)
-        numeric_retries = 0
-        rebuilds += int(nreb)
-        _log_rows(thermo, np.asarray(pe), np.asarray(ke), it, state.step,
-                  n_atoms, n_steps, log_every)
-        it += n_sub
-        steps_since_ckpt += n_sub
-        if (checkpoint_dir and checkpoint_every
-                and steps_since_ckpt >= checkpoint_every):
-            path = save_md_checkpoint(
-                checkpoint_dir, state.step + it, carry, box, grid,
-                extra=dict(dt=dt_cur, e_ref=e_ref, n_atoms=n_atoms))
-            events.append(RecoveryEvent(state.step + it, 'checkpoint',
-                                        dict(path=str(path))))
-            steps_since_ckpt = 0
+            # clean chunk: commit to the carry and the thermo log
+            carry = dict(pos=pos, vel=vel, f=f, nbr_idx=nbr_idx,
+                         shifts=shifts, mask=mask, pos_ref=pos_ref,
+                         flags=flags)
+            numeric_retries = 0
+            with span('md.log'):
+                rebuilds += int(nreb)
+                _log_rows(thermo, np.asarray(pe), np.asarray(ke), it,
+                          state.step, n_atoms, n_steps, log_every)
+            it += n_sub
+            steps_since_ckpt += n_sub
+            if (checkpoint_dir and checkpoint_every
+                    and steps_since_ckpt >= checkpoint_every):
+                path = save_md_checkpoint(
+                    checkpoint_dir, state.step + it, carry, box, grid,
+                    extra=dict(dt=dt_cur, e_ref=e_ref, n_atoms=n_atoms))
+                events.append(RecoveryEvent(state.step + it, 'checkpoint',
+                                            dict(path=str(path))))
+                steps_since_ckpt = 0
     cache['device_rebuilds'] = rebuilds
     state.pos = np.asarray(carry['pos'])
     state.vel = np.asarray(carry['vel'])

@@ -115,6 +115,38 @@ def test_device_loop_zero_host_transfers_large_n():
     assert abs(e[-1] - e[0]) < 1e-6 * max(abs(e[0]), 1.0)
 
 
+def test_device_loop_records_host_spans():
+    """The device loop's host phases land in the span ring: one md.run
+    around the seed and two chunks, each chunk holding its dispatch, its
+    wait on the flags and its log read-back, in that order."""
+    from repro.runtime import trace
+    cfg = SnapConfig(twojmax=2, rcut=4.7)
+    rng = np.random.default_rng(3)
+    beta = jnp.asarray(rng.normal(size=cfg.ncoeff) * 5e-3)
+    pos, box = paper_box(natoms=54)
+    state = MDState(pos=perturb(pos, 0.03, seed=4),
+                    vel=init_velocities(len(pos), 300.0, seed=5), box=box)
+    trace.reset()
+    run_nve(cfg, beta, 0.0, state, n_steps=4, dt=0.0005, log_every=2,
+            loop='device', impl='adjoint', skin=0.05)
+    spans = sorted(trace.snapshot(), key=lambda s: s[1])
+    runs = [s for s in spans if s[0] == 'md.run']
+    assert len(runs) == 1 and runs[0][3] is None
+    r0, r1 = runs[0][1], runs[0][1] + runs[0][2]
+
+    def within(s, lo, hi):
+        return lo <= s[1] and s[1] + s[2] <= hi
+
+    assert [s[0] for s in spans if s[3] == 'md.run'] == [
+        'md.seed', 'md.chunk', 'md.chunk']
+    chunks = [s for s in spans if s[0] == 'md.chunk']
+    for c in chunks:
+        assert within(c, r0, r1)
+        inner = [s for s in spans if s[3] == 'md.chunk'
+                 and within(s, c[1], c[1] + c[2])]
+        assert [s[0] for s in inner] == ['md.dispatch', 'md.wait', 'md.log']
+
+
 def test_device_cache_rejects_mismatched_grid():
     """fn_cache reuse across a different box geometry must raise, not
     silently reuse a CellGrid whose stencil no longer covers rcut+skin."""

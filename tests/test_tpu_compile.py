@@ -13,6 +13,8 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and it keeps it until it
 exits.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -101,17 +103,39 @@ def test_kernel_compiles_for_v5e(one_chip, name, twojmax):
     assert compile_for(one_chip, fn, *shapes) == 1
 
 
-def test_force_pipeline_compiles_for_v5e(one_chip):
-    """The whole f32 kernel force call at 2J=8 holds exactly the three
-    Mosaic kernels (U, Y, fused dE) — none is left to interpret mode."""
+@pytest.fixture(scope='module')
+def force_pipeline_hlo(one_chip):
+    """Optimized HLO of the whole f32 kernel force call at 2J=8 (half
+    layout), compiled once for the tests that read it."""
     from repro.core.snap import SnapConfig, energy_forces
     cfg = SnapConfig(twojmax=8, rcut=4.7)
     f32 = jnp.float32
     pair = (2000, NNBOR)
-    n = compile_for(
-        one_chip,
+    shapes = [((cfg.ncoeff,), f32), (pair, f32), (pair, f32), (pair, f32),
+              (pair, jnp.int32), (pair, jnp.bool_)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(
         lambda b, dx, dy, dz, ni, m: energy_forces(
-            cfg, b, 0.0, dx, dy, dz, ni, m, impl='kernel', interpret=False),
-        ((cfg.ncoeff,), f32), (pair, f32), (pair, f32), (pair, f32),
-        (pair, jnp.int32), (pair, jnp.bool_))
-    assert n == 3
+            cfg, b, 0.0, dx, dy, dz, ni, m, impl='kernel', interpret=False)
+    ).lower(*args).compile().as_text()
+
+
+def test_force_pipeline_compiles_for_v5e(force_pipeline_hlo):
+    """The whole f32 kernel force call at 2J=8 holds exactly the three
+    Mosaic kernels (U, Y, fused dE) — none is left to interpret mode."""
+    assert force_pipeline_hlo.count(
+        'custom_call_target="tpu_custom_call"') == 3
+
+
+def test_force_pipeline_kernels_carry_their_names(force_pipeline_hlo):
+    """Each Mosaic kernel's instruction is named after its pallas_call, so
+    a profile's op text finds it by name alone: exactly one custom-call
+    each named %snap_u_half.*, %snap_y_half.*, %snap_fused_de_half.*."""
+    names = re.findall(r'^\s*(?:ROOT )?%([\w.-]+) = .*'
+                       r'custom_call_target="tpu_custom_call"',
+                       force_pipeline_hlo, flags=re.M)
+    kernels = ('snap_u_half', 'snap_y_half', 'snap_fused_de_half')
+    for kernel in kernels:
+        assert len([n for n in names
+                    if re.fullmatch(rf'{kernel}\.\d+', n)]) == 1, names
+    assert len(names) == len(kernels)
