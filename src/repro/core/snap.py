@@ -294,7 +294,7 @@ def energy_forces(cfg: SnapConfig, beta, beta0, dx, dy, dz, nbr_idx, mask,
     impl='kernel' extras (forwarded to ``snap_force_pipeline``):
     ``layout='half'|'full'`` selects the symmetric half-index planes
     (default) vs the v1 full planes, ``y_tile`` sizes the Y kernel's COO
-    tiles, and ``mxu_dtype`` (e.g. ``jnp.bfloat16``) casts the Y matmul
+    tiles, and ``mxu_dtype`` (e.g. ``jnp.bfloat16``) rounds the Y walk's
     operands while accumulation stays in ``dtype``.
     """
     if impl == 'adjoint':

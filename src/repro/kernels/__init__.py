@@ -3,12 +3,14 @@
 # kernel. Leave this package empty if the paper has none.
 #
 # SNAP kernel suite (paper Sec. VI): snap_u (Wigner recursion),
-# snap_y (adjoint one-hot-matmul contraction), snap_fused_de[_half]
-# (dual-number dU + force contraction).  ``ops.snap_force_pipeline``
-# chains them in one canonical [*, natoms_pad] device layout —
-# half-index planes by default (layout='half'), full planes kept for
-# A/B (layout='full'); mxu_dtype=bfloat16 opts the Y matmuls into the
-# MXU's low-precision rate with full-precision accumulation.
+# snap_y (adjoint contraction: a VPU walk over the static CG table on
+# the half planes, one-hot MXU matmuls on the full ones),
+# snap_fused_de[_half] (dual-number dU + force contraction).
+# ``ops.snap_force_pipeline`` chains them in one canonical
+# [*, natoms_pad] device layout — half-index planes by default
+# (layout='half'), full planes kept for A/B (layout='full');
+# mxu_dtype=bfloat16 rounds the half walk's U rows, coefficients and
+# products to bfloat16, with float32 accumulation.
 
 from .ops import (energy_forces_kernel, half_planes_to_full,
                   snap_dedr_kernel, snap_force_pipeline, snap_ui_kernel,

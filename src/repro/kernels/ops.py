@@ -19,9 +19,9 @@ halved space through mirror-folded COO tables, and the fused-dE kernel
 consumes the half planes natively — no full-plane tensor exists between
 entry and force assembly.  ``layout='full'`` keeps the v1 full-plane
 pipeline alive for A/B benchmarking (see benchmarks/b_kernels.py).
-``mxu_dtype`` (half layout only) casts the Y kernel's matmul operands,
-e.g. ``jnp.bfloat16`` for the MXU's native low-precision rate with f32
-accumulation.
+``mxu_dtype`` (half layout only) sets the precision of the Y walk's
+operands: ``jnp.bfloat16`` rounds its U rows, coefficients and products
+to bfloat16, with f32 accumulation.
 
 Names in a profile: each kernel's ``pallas_call`` is named (``snap_u_half``,
 ``snap_y_half``, ``snap_fused_de_half``; ``snap_u``, ``snap_y``,
@@ -46,8 +46,8 @@ from .common import LANES
 from .snap_fused_de import snap_fused_de_pallas
 from .snap_fused_de_half import snap_fused_de_half_pallas
 from .snap_u import snap_u_half_pallas, snap_u_pallas
-from .snap_y import (Y_TILE, snap_y_half_pallas, snap_y_pallas, y_coef,
-                     y_coef_half)
+from .snap_y import (Y_HALF_TILE, Y_TILE, snap_y_half_pallas, snap_y_pallas,
+                     y_coef, y_coef_half)
 
 LAYOUTS = ('half', 'full')
 
@@ -112,7 +112,8 @@ def energy_from_ylist_lanes(cfg: SnapConfig, ut_r, ut_i, y_r, y_i,
 def snap_force_pipeline(cfg: SnapConfig, beta, beta0, dx, dy, dz, nbr_idx,
                         mask, dtype=jnp.float32, interpret=None,
                         with_energy=True, layout: str = 'half',
-                        y_tile: int = Y_TILE, mxu_dtype=None, shard=None):
+                        y_tile: int | None = None, mxu_dtype=None,
+                        shard=None):
     """Zero-relayout kernel pipeline: Pallas U -> Pallas Y -> Pallas fused dE.
 
     Every inter-stage tensor stays in the canonical [*, natoms_pad] device
@@ -120,12 +121,18 @@ def snap_force_pipeline(cfg: SnapConfig, beta, beta0, dx, dy, dz, nbr_idx,
     axis) is the only stage input computed at the JAX level.
 
     layout='half' (default): all inter-stage planes are half-index
-    ``[idxu_half_max, natoms_pad]`` — ~1.9x less HBM plane traffic and
-    ~2x smaller Y matmuls; no full plane is ever materialized.
-    layout='full': the v1 full-plane pipeline, kept for A/B measurement.
+    ``[idxu_half_max, natoms_pad]`` — ~1.9x less HBM plane traffic, and
+    Y is the sparse VPU walk over the half CG table; no full plane is
+    ever materialized.
+    layout='full': the v1 full-plane pipeline (one-hot MXU Y), kept for
+    A/B measurement.
 
-    mxu_dtype: optional dtype for the Y kernel's matmul operands (half
-    layout only), e.g. ``jnp.bfloat16``; accumulation stays in ``dtype``.
+    y_tile: COO entries per Y grid step (default: the layout's own,
+    ``Y_HALF_TILE`` or ``Y_TILE``).
+
+    mxu_dtype: optional precision of the Y walk's operands (half layout
+    only), e.g. ``jnp.bfloat16`` rounds its U rows, coefficients and
+    products; accumulation stays in ``dtype``.
 
     shard: optional ``(axis_name, n_shards)`` for the atom-sharded path —
     the Pallas stages are untouched (atoms already live on the lane axis,
@@ -144,6 +151,7 @@ def snap_force_pipeline(cfg: SnapConfig, beta, beta0, dx, dy, dz, nbr_idx,
                rfac0=cfg.rfac0, switch_flag=cfg.switch_flag,
                interpret=interpret)
 
+    y_tile = y_tile or (Y_HALF_TILE if layout == 'half' else Y_TILE)
     if layout == 'half':
         ut_r, ut_i = snap_u_half_pallas(disp, **geo)
         with jax.named_scope('snap.self_planes'):
@@ -317,7 +325,7 @@ def snap_ui_kernel(cfg: SnapConfig, dx, dy, dz, mask, dtype=jnp.float32,
 
 
 def snap_yi_kernel(cfg: SnapConfig, ulisttot, beta, dtype=jnp.float32,
-                   interpret=None, y_tile: int = Y_TILE,
+                   interpret=None, y_tile: int | None = None,
                    layout: str = 'half', mxu_dtype=None):
     """Adjoint Y via the Pallas kernel: complex [natoms, idxu_max].
 
@@ -334,6 +342,7 @@ def snap_yi_kernel(cfg: SnapConfig, ulisttot, beta, dtype=jnp.float32,
     natoms = ulisttot.shape[0]
     pad = (-natoms) % LANES
     ut = ulisttot[:, idx.half_to_full] if layout == 'half' else ulisttot
+    y_tile = y_tile or (Y_HALF_TILE if layout == 'half' else Y_TILE)
     ut_r = jnp.pad(ut.real.T.astype(dtype), [(0, 0), (0, pad)])
     ut_i = jnp.pad(ut.imag.T.astype(dtype), [(0, 0), (0, pad)])
     if layout == 'half':

@@ -1,44 +1,48 @@
-"""Pallas TPU kernel: SNAP compute_Yi (paper Sec. IV adjoint, Sec. VI kernel).
+"""Pallas TPU kernels: SNAP compute_Yi (paper Sec. IV adjoint, Sec. VI kernel).
 
 The adjoint accumulation Y[jju] += cg * beta[jjb] * U[src1] * U[src2] is the
 one irregular-gather stage of the pipeline.  The GPU implementations balance
-it with warp-level work distribution (LAMMPS-KOKKOS, Kokkos-MTP); the TPU
-adaptation here turns the static COO Clebsch-Gordan tables into *one-hot
-matmuls* so the whole contraction runs on the MXU:
+it with warp-level work distribution (LAMMPS-KOKKOS, Kokkos-MTP).  The CG
+tables are compile-time constants and every atom runs the same table, so
+on the TPU the atoms go on lanes and the table is walked entry by entry.
+
+The **half-plane** kernel (:func:`snap_y_half_pallas`, the pipeline's
+default) is that walk, on the VPU.  U planes come in as
+``[idxu_half_max, natoms_pad]`` (the mirror fold
+``u(j,mb,ma) = (-1)^(mb+ma) conj(u(j,j-mb,j-ma))`` is pre-applied to the
+COO tables at build time — see ``SnapIndex.z_half_*``).  Per lane block
+the kernel packs U and its conjugate into a VMEM scratch in which one U
+row of the whole block is a run of whole vregs (lane tiles on sublanes),
+then for each table entry reads its two factor rows by dynamic row
+offset, forms the complex product in f32, scales it by the entry's
+coefficient ``cg * y_fac * beta[y_jjb]`` and sums it.  The table is
+sorted by destination and padded so every ``Y_GROUP`` entries share one:
+a group sums in registers and touches the Y scratch once.  Table offsets
+and coefficients stream through SMEM one chunk per step of the inner
+grid axis; the conjugation signs are folded into the factor rows (a
+conjugated factor reads the conj U half of the scratch), so the walk
+does no sign arithmetic.  The beta factor is a runtime [nnz] gather done
+once at the JAX level (no natoms axis), so the kernel is beta-agnostic
+and Z is never materialized — the paper's compute_Yi fusion.
+
+The walk issues the algorithm's 10 flops per entry and atom.  It
+replaced a one-hot MXU contraction that issued 170-760x that work at
+the 2J=8 and 2J=14 sizes: Y 53.0 and 596 ms an evaluation on one v5e.
+
+The **full-plane** kernel (:func:`snap_y_pallas`, ``layout='full'``, kept
+for A/B) is that one-hot contraction:
 
     Y  =  sum_tiles  S_t @ ((G1_t @ U) * (G2_t @ U))        (complex)
 
 where G1/G2 are [tile, idxu_max] one-hot gather matrices built in-kernel
 from int32 index rows (broadcasted-iota compare — no dynamic indexing), and
-S folds the scatter destination one-hot with the per-entry coefficient
-``cg * y_fac * beta[y_jjb]``.  The beta factor is a runtime [nnz] gather
-done once at the JAX level (no natoms axis), so the kernel itself is
-beta-agnostic and Z is never materialized — the paper's compute_Yi fusion.
-
-Layout: atoms on the 128-wide lane axis ([idxu_max, natoms_pad] planes,
-identical to snap_u / snap_fused_de), grid = (lane tiles, COO tiles) with
-the partial-Y accumulator revisiting its VMEM block across the inner COO
-axis.  Index tables are stored ``[ntiles, 1, tile]`` and stream through
-VMEM one ``[1, tile]`` row at a time: the unit middle axis makes each
-block span its array's full last two dimensions, which Mosaic requires of
-a block whose sublane extent (1) is not a multiple of 8.
-
-The **half-plane** variant (:func:`snap_y_half_pallas`) indexes the
-symmetric half space instead: U planes come in as ``[idxu_half_max, L]``
-(the mirror fold ``u(j,mb,ma) = (-1)^(mb+ma) conj(u(j,j-mb,j-ma))`` is
-pre-applied to the COO tables at build time — see
-``SnapIndex.z_half_*``), gathers carry a per-entry ±1 conjugation factor
-on the imaginary plane, and the scatter lands in the half space too.
-Both one-hot operand axes shrink ~1.9x, so matmul FLOPs, one-hot build
-work, and U/Y plane traffic all near-halve; dead destination entries
-(weight-0 middle-row columns) are dropped from the COO axis as well.
-
-A ``mxu_dtype`` knob (default: the plane dtype) casts every operand
-feeding ``jnp.dot`` — one-hots and U planes on the gather side, the
-coefficient-scaled scatter one-hot and the Z products on the scatter
-side — while ``preferred_element_type`` keeps accumulation in the plane
-dtype.  An f32 feed asks for full-precision (``HIGHEST``) MXU passes.  ``mxu_dtype=jnp.bfloat16`` opens the MXU's native bf16 rate on
-the one pipeline stage that is matmul-bound.
+S folds the scatter destination one-hot with the per-entry coefficient.
+Layout: grid = (lane tiles, COO tiles) with the partial-Y accumulator
+revisiting its VMEM block across the inner COO axis.  Index tables are
+stored ``[ntiles, 1, tile]`` and stream through VMEM one ``[1, tile]`` row
+at a time: the unit middle axis makes each block span its array's full
+last two dimensions, which Mosaic requires of a block whose sublane extent
+(1) is not a multiple of 8.
 """
 
 from __future__ import annotations
@@ -56,10 +60,15 @@ from repro.core.indices import build_index
 from .common import I0, LANES, plane_spec, resolve_interpret
 
 Y_TILE = 512   # COO entries per grid step; 128-multiple keeps tiles aligned
+Y_HALF_TILE = 2048   # COO entries per SMEM chunk of the half-layout walk
+Y_GROUP = 8          # entries that share a destination, summed in registers
+Y_UNROLL = 2         # groups per iteration of the walk's loop
+Y_LANE_TILES = 32    # most 128-lane tiles in one lane block of the walk
+Y_HALF_VMEM = 64 * 1024 * 1024   # the walk's VMEM budget for its blocks
 # scoped-VMEM limit of the full-plane Y kernel.  Mosaic's 16 MiB default is
 # too small for it at 2J=14 (two [tile, 1240] one-hots plus the f32
-# multi-pass matmul scratch); a v5e core has 128 MiB.  The half-plane kernel
-# fits the default.
+# multi-pass matmul scratch); a v5e core has 128 MiB.  The half-plane walk
+# sizes its own limit from its blocks (``_y_half_block``).
 Y_VMEM_LIMIT = 48 * 1024 * 1024
 
 
@@ -181,129 +190,207 @@ def snap_y_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_TILE, interpret=None):
 
 
 # ---------------------------------------------------------------------------
-# half-plane variant
+# half-plane variant: sparse walk over the static CG table on the VPU
 # ---------------------------------------------------------------------------
 
 @lru_cache(maxsize=16)
 def _y_half_coo_tiles(twojmax: int, tile: int):
-    """Half-space COO tables padded to [ntiles, 1, tile] (pad: cg = 0).
+    """Half-space COO table in walk order, padded to [ntiles, 1, chunk].
 
-    Returns (src1, src2, sig1, sig2, dest, cg, jjz): half-space gather
-    indices, ±1 conjugation factors for the imaginary gathers, half-space
-    scatter destination, mirror-folded CG product, and the idxz row of
-    each entry (runtime beta gather).
+    Entries are sorted by destination and every destination's run is
+    padded to a multiple of ``Y_GROUP`` (pad entries: cg = 0), so each
+    group of ``Y_GROUP`` consecutive entries shares one destination.
+    ``chunk`` is ``tile``, or less for a table shorter than that, which
+    is then one chunk of whole loop iterations.
+
+    Returns (fac1, fac2, dest, cg, jjz): the two factors' rows in the
+    walk's factor table ``[U; conj U]`` (``src + idxu_half_max`` where
+    the mirror conjugates the factor, σ = -1), the destination of each
+    group ``[ntiles, 1, chunk // Y_GROUP]``, the mirror-folded CG
+    product, and the idxz row of each entry (runtime beta gather).
     """
+    step = Y_GROUP * Y_UNROLL
+    assert tile % step == 0, (tile, step)
     idx = build_index(twojmax)
-    nnz = idx.z_half_dest.shape[0]
-    ntiles = max(1, -(-nnz // tile))
-    pad = ntiles * tile - nnz
+    nh = idx.idxu_half_max
+    order = np.lexsort((idx.z_half_src2, idx.z_half_src1, idx.z_half_dest))
+    dest = idx.z_half_dest[order]
+    counts = np.bincount(dest, minlength=nh)
+    padded = -(-counts // Y_GROUP) * Y_GROUP
+    run0 = np.cumsum(padded) - padded          # first slot of each run
+    first = np.cumsum(counts) - counts         # first sorted entry of each
+    pos = run0[dest] + np.arange(dest.size) - first[dest]
+    tile = min(tile, -(-int(padded.sum()) // step) * step)
+    ntiles = -(-int(padded.sum()) // tile)
 
-    def p(a, dtype, fill=0):
-        return np.pad(a, (0, pad), constant_values=fill) \
-            .astype(dtype).reshape(ntiles, 1, tile)
+    def place(a, dtype):
+        out = np.zeros(ntiles * tile, dtype)
+        out[pos] = a[order]
+        return out.reshape(ntiles, 1, tile)
 
-    return (p(idx.z_half_src1, np.int32),
-            p(idx.z_half_src2, np.int32),
-            p(idx.z_half_sig1, np.float64, 1),
-            p(idx.z_half_sig2, np.float64, 1),
-            p(idx.z_half_dest, np.int32),
-            p(idx.z_half_cg, np.float64),
-            p(idx.z_half_jjz, np.int32))
+    group_dest = np.zeros(ntiles * tile // Y_GROUP, np.int32)
+    group_dest[:padded.sum() // Y_GROUP] = np.repeat(np.arange(nh),
+                                                      padded // Y_GROUP)
+    return (place(idx.z_half_src1 + nh * (idx.z_half_sig1 < 0), np.int32),
+            place(idx.z_half_src2 + nh * (idx.z_half_sig2 < 0), np.int32),
+            group_dest.reshape(ntiles, 1, tile // Y_GROUP),
+            place(idx.z_half_cg, np.float64),
+            place(idx.z_half_jjz, np.int32))
 
 
-def _snap_y_half_kernel(src1_ref, src2_ref, sig1_ref, sig2_ref, dest_ref,
-                        coef_ref, ut_r_ref, ut_i_ref, y_r_ref, y_i_ref, *,
-                        idxu_half_max, tile, dtype, mxu_dtype):
-    """One (lane tile, COO tile) step on the halved index space.
+@lru_cache(maxsize=16)
+def _y_half_offsets(twojmax: int, tile: int, step: int):
+    """The walk's table as scratch row offsets: factor and destination
+    rows times ``step``, the scratch rows one table row spans."""
+    fac1, fac2, dest, _, _ = _y_half_coo_tiles(twojmax, tile)
+    return fac1 * step, fac2 * step, dest * step
 
-    The imaginary gathers carry the mirror conjugation as a per-entry ±1
-    factor: with u_full = s·conj^c(u_half), writing ṽi = σ·vi (σ = -1
-    where c) keeps the complex-multiply form unchanged while s folds
-    into the scatter coefficient.  σ is constant along each one-hot row,
-    so it is applied *after* the gather matmul on the [tile, LANES]
-    result — no signed one-hot copy ever exists — and the body is the
-    full kernel's body with two extra [1, tile] sign rows and every
-    matmul ~2x smaller.
+
+def _y_half_block(nh: int, natoms_pad: int, itemsize: int):
+    """(lane tiles per block, sublanes per part, VMEM bytes) of the walk.
+
+    As many 128-lane tiles as fit ``Y_HALF_VMEM``, at most
+    ``Y_LANE_TILES``, spread evenly over the blocks: every entry then
+    works on whole vregs of as many atoms as the budget allows."""
+    tiles = natoms_pad // LANES
+    most = Y_LANE_TILES
+    while True:
+        lane_tiles = -(-tiles // -(-tiles // most))
+        rows = -(-lane_tiles // 8) * 8
+        # [U; conj U] and Y scratch, Re and Im each; U in and Y out blocks
+        need = itemsize * LANES * nh * (6 * rows + 4 * lane_tiles)
+        if need <= Y_HALF_VMEM or most == 1:
+            return lane_tiles, rows, need
+        most //= 2
+
+
+def _snap_y_half_kernel(fac1_ref, fac2_ref, dest_ref, coef_ref, ut_r_ref,
+                        ut_i_ref, y_r_ref, y_i_ref, u_s, y_s, *, nh,
+                        lane_tiles, rows, ngroups, ntiles, dtype, mxu_dtype):
+    """One (lane block, COO chunk) step of the sparse walk.
+
+    Table refs are SMEM scalars of one chunk (offsets already scaled to
+    scratch rows); ut/y refs are the ``[nh, lane_tiles * LANES]`` lane
+    block.  The scratches hold the block packed so that one table row is
+    ``2 * rows`` consecutive sublanes, Re then Im: lane tile ``c`` of
+    factor ``f`` sits at rows ``2 * rows * f + c`` and ``... + rows + c``
+    (``u_s`` holds U, then conj U; ``y_s`` the Y accumulator).  The
+    packing is a strided copy once per lane block; every entry then reads
+    and writes whole vregs.
     """
     t = pl.program_id(1)
+    step = 2 * rows
+    rounding = jnp.dtype(mxu_dtype) != jnp.dtype(dtype)
+
+    def rnd(x):
+        return x.astype(mxu_dtype).astype(dtype) if rounding else x
+
+    def strided(start):
+        return pl.ds(start, nh, stride=step)
 
     @pl.when(t == 0)
-    def _init():
-        y_r_ref[...] = jnp.zeros((idxu_half_max, LANES), dtype)
-        y_i_ref[...] = jnp.zeros((idxu_half_max, LANES), dtype)
+    def _pack():
+        for c in range(lane_tiles):
+            u_r = rnd(ut_r_ref[:, pl.ds(c * LANES, LANES)])
+            u_i = rnd(ut_i_ref[:, pl.ds(c * LANES, LANES)])
+            u_s[strided(c), :] = u_r
+            u_s[strided(rows + c), :] = u_i
+            u_s[strided(nh * step + c), :] = u_r
+            u_s[strided(nh * step + rows + c), :] = -u_i
+        y_s[...] = jnp.zeros(y_s.shape, dtype)
 
-    iu_g = jax.lax.broadcasted_iota(jnp.int32, (tile, idxu_half_max), 1)
-    g1 = (src1_ref[0, :][:, None] == iu_g).astype(mxu_dtype)
-    g2 = (src2_ref[0, :][:, None] == iu_g).astype(mxu_dtype)
+    def factor(off):
+        x = u_s[pl.ds(pl.multiple_of(off, step), step), :]
+        return x[:rows], x[rows:]
 
-    ut_r = ut_r_ref[...].astype(mxu_dtype)
-    ut_i = ut_i_ref[...].astype(mxu_dtype)
-    dot = partial(jnp.dot, preferred_element_type=dtype,
-                  precision=_precision(mxu_dtype))
-    v1r = dot(g1, ut_r)
-    v1i = dot(g1, ut_i) * sig1_ref[0, :][:, None]   # σ1 · Im(u_half[src1])
-    v2r = dot(g2, ut_r)
-    v2i = dot(g2, ut_i) * sig2_ref[0, :][:, None]   # σ2 · Im(u_half[src2])
-    prod_r = v1r * v2r - v1i * v2i
-    prod_i = v1r * v2i + v1i * v2r
+    def group(g):
+        acc_r = jnp.zeros((rows, LANES), dtype)
+        acc_i = jnp.zeros((rows, LANES), dtype)
+        for e in range(Y_GROUP):
+            k = g * Y_GROUP + e
+            u1r, u1i = factor(fac1_ref[k])
+            u2r, u2i = factor(fac2_ref[k])
+            c = coef_ref[k]
+            acc_r = acc_r + c * rnd(u1r * u2r - u1i * u2i)
+            acc_i = acc_i + c * rnd(u1r * u2i + u1i * u2r)
+        d = pl.multiple_of(dest_ref[g], step)
+        y_s[pl.ds(d, rows), :] += acc_r
+        y_s[pl.ds(pl.multiple_of(d + rows, rows), rows), :] += acc_i
 
-    iu_s = jax.lax.broadcasted_iota(jnp.int32, (idxu_half_max, tile), 0)
-    s = ((dest_ref[0, :][None, :] == iu_s).astype(dtype)
-         * coef_ref[0, :][None, :]).astype(mxu_dtype)
-    y_r_ref[...] += dot(s, prod_r.astype(mxu_dtype))
-    y_i_ref[...] += dot(s, prod_i.astype(mxu_dtype))
+    def body(i, carry):
+        for u in range(Y_UNROLL):
+            group(i * Y_UNROLL + u)
+        return carry
+
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(ngroups // Y_UNROLL), body,
+                      jnp.int32(0))
+
+    @pl.when(t == ntiles - 1)
+    def _unpack():
+        for c in range(lane_tiles):
+            y_r_ref[:, pl.ds(c * LANES, LANES)] = y_s[strided(c), :]
+            y_i_ref[:, pl.ds(c * LANES, LANES)] = y_s[strided(rows + c), :]
 
 
-def y_coef_half(beta, twojmax: int, tile: int = Y_TILE):
+def y_coef_half(beta, twojmax: int, tile: int = Y_HALF_TILE):
     """Runtime per-entry coefficient for the half-space COO table:
     ``cg_folded * y_fac * beta[y_jjb]`` — mirror signs s1·s2 are already
     inside ``cg_folded`` (``SnapIndex.z_half_cg``)."""
     idx = build_index(twojmax)
-    _, _, _, _, _, cg, jjz = _y_half_coo_tiles(twojmax, tile)
+    _, _, _, cg, jjz = _y_half_coo_tiles(twojmax, tile)
     betaj = jnp.asarray(idx.y_fac, beta.dtype) * beta[..., idx.y_jjb]
     return jnp.asarray(cg, beta.dtype) * betaj[..., jjz]
 
 
-def snap_y_half_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_TILE,
+def snap_y_half_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_HALF_TILE,
                        mxu_dtype=None, interpret=None):
     """ut_r/ut_i: [idxu_half_max, natoms_pad] half Ulisttot planes (self
-    included); coef: [ntiles, 1, tile] from :func:`y_coef_half`.
+    included); coef: [ntiles, 1, chunk] from :func:`y_coef_half` with
+    the same ``tile``.
 
     Returns (y_r, y_i): [idxu_half_max, natoms_pad] adjoint half planes —
     exactly the left rows of :func:`repro.core.bispectrum.compute_ylist`
     on the weighted support (dropped weight-0 middle-row columns are 0).
 
-    mxu_dtype: dtype of the operands fed to ``jnp.dot`` (default: the
-    plane dtype).  ``jnp.bfloat16`` halves MXU-feed bytes; accumulation
-    stays in the plane dtype via ``preferred_element_type``.
+    mxu_dtype: precision of the contraction's operands (default: the
+    plane dtype).  ``jnp.bfloat16`` rounds the U rows, the coefficients
+    and the complex products to bfloat16 before they are scaled and
+    summed; accumulation stays in the plane dtype.
     """
     idx = build_index(twojmax)
-    iu, natoms_pad = ut_r.shape
-    assert iu == idx.idxu_half_max and natoms_pad % LANES == 0
+    nh, natoms_pad = ut_r.shape
+    assert nh == idx.idxu_half_max and natoms_pad % LANES == 0
     dtype = ut_r.dtype
     mxu_dtype = jnp.dtype(mxu_dtype) if mxu_dtype is not None else dtype
-    src1, src2, sig1, sig2, dest, _, _ = _y_half_coo_tiles(twojmax, tile)
-    ntiles = src1.shape[0]
-    assert coef.shape == src1.shape, (coef.shape, src1.shape)
-    coef = coef.astype(dtype)
+    lane_tiles, rows, vmem = _y_half_block(nh, natoms_pad,
+                                           jnp.dtype(dtype).itemsize)
+    fac1, fac2, dest = _y_half_offsets(twojmax, tile, 2 * rows)
+    assert coef.shape == fac1.shape, (coef.shape, fac1.shape)
+    coef = coef.astype(mxu_dtype).astype(dtype)
 
-    kernel = partial(_snap_y_half_kernel, idxu_half_max=idx.idxu_half_max,
-                     tile=tile, dtype=dtype, mxu_dtype=mxu_dtype)
-    grid = (natoms_pad // LANES, ntiles)
-    nh = idx.idxu_half_max
-    coo_spec = pl.BlockSpec((None, 1, tile), lambda i, t: (t, I0, I0))
-    u_spec = plane_spec(nh)
+    ntiles, _, chunk = fac1.shape
+    kernel = partial(_snap_y_half_kernel, nh=nh, lane_tiles=lane_tiles,
+                     rows=rows, ngroups=chunk // Y_GROUP, ntiles=ntiles,
+                     dtype=dtype, mxu_dtype=mxu_dtype)
+    width = lane_tiles * LANES
+
+    def smem(n):
+        return pl.BlockSpec((None, None, n), lambda i, t: (t, I0, I0),
+                            memory_space=pltpu.SMEM)
+    plane = pl.BlockSpec((nh, width), lambda i, t: (I0, i),
+                         pipeline_mode=pl.Buffered(1))
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[coo_spec, coo_spec, coo_spec, coo_spec, coo_spec,
-                  coo_spec, u_spec, u_spec],
-        out_specs=[u_spec, u_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((nh, natoms_pad), dtype),
-            jax.ShapeDtypeStruct((nh, natoms_pad), dtype)],
+        grid=(pl.cdiv(natoms_pad, width), ntiles),
+        in_specs=[smem(chunk), smem(chunk), smem(chunk // Y_GROUP),
+                  smem(chunk), plane, plane],
+        out_specs=[plane, plane],
+        out_shape=[jax.ShapeDtypeStruct((nh, natoms_pad), dtype)] * 2,
+        scratch_shapes=[pltpu.VMEM((2 * nh * 2 * rows, LANES), dtype),
+                        pltpu.VMEM((nh * 2 * rows, LANES), dtype)],
         interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem + 16 * 1024 * 1024),
         name='snap_y_half',
-    )(jnp.asarray(src1), jnp.asarray(src2),
-      jnp.asarray(sig1, dtype), jnp.asarray(sig2, dtype),
-      jnp.asarray(dest), coef, ut_r, ut_i)
+    )(jnp.asarray(fac1), jnp.asarray(fac2), jnp.asarray(dest), coef,
+      ut_r, ut_i)

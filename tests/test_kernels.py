@@ -10,9 +10,11 @@ import numpy as np
 import pytest
 
 from repro.core import bispectrum as bs
+from repro.core.indices import build_index
 from repro.core.snap import (SnapConfig, _pair_geometry,
                              energy_forces_adjoint, energy_forces_autodiff)
 from repro.core.ulist import compute_ulist, compute_ulisttot
+from repro.kernels import snap_y
 from repro.kernels.ops import (_kernel_layout, energy_forces_kernel,
                                half_planes_to_full, snap_dedr_kernel,
                                snap_force_pipeline, snap_ui_kernel,
@@ -99,20 +101,41 @@ def _oracle_ulisttot(cfg, disp, mask):
     return compute_ulisttot(u, geom.sfac, ok, idx, cfg.wself)
 
 
-@pytest.mark.parametrize('layout', ['half', 'full'])
-@pytest.mark.parametrize('twojmax', [4, 8])
-@pytest.mark.parametrize('dtype', [jnp.float32, jnp.float64])
-def test_snap_y_kernel_parity(twojmax, dtype, layout):
-    """Pallas one-hot-matmul Y == bs.compute_ylist on identical Ulisttot.
+def _y_parity_cases():
+    """(dtype, twojmax, layout, natoms, most lane tiles a block): 9 atoms
+    fill one lane tile; 300 and 1,100 atoms split the half walk into
+    several lane blocks, the last one partial."""
+    cases = []
+    for dname, dtype in (('float32', jnp.float32), ('float64', jnp.float64)):
+        for twojmax in (4, 8):
+            for layout in ('half', 'full'):
+                cases.append(pytest.param(dtype, twojmax, layout, 9, None,
+                                          id=f'{dname}-{twojmax}-{layout}'))
+            for natoms, most in ((300, 2), (1100, 8)):
+                cases.append(pytest.param(
+                    dtype, twojmax, 'half', natoms, most,
+                    id=f'{dname}-{twojmax}-half-n{natoms}'))
+    return cases
+
+
+@pytest.mark.parametrize('dtype,twojmax,layout,natoms,most',
+                         _y_parity_cases())
+def test_snap_y_kernel_parity(dtype, twojmax, layout, natoms, most,
+                              monkeypatch):
+    """Pallas Y == bs.compute_ylist on identical Ulisttot.
 
     Acceptance bar: <= 1e-5 relative (f32) / 1e-10 (f64) at twojmax=8.
     The half layout is compared on the weighted support (dedr_weight > 0):
     it drops the COO entries scattering into weight-0 positions that no
     contraction ever reads, so those read back 0 instead of the reference
-    value; the full layout matches everywhere.
+    value; the full layout matches everywhere.  ``most`` caps the half
+    walk's lane tiles a block, so that 300 atoms (3 tiles) run as blocks
+    of 2 + 1 and 1,100 atoms (9 tiles) as 5 + 4.
     """
+    if most is not None:
+        monkeypatch.setattr(snap_y, 'Y_LANE_TILES', most)
     cfg = SnapConfig(twojmax=twojmax, rcut=3.0)
-    _, disp, _, mask, _ = make_cluster(natoms=9, nnbor=6, seed=twojmax)
+    _, disp, _, mask, _ = make_cluster(natoms=natoms, nnbor=6, seed=twojmax)
     ut = _oracle_ulisttot(cfg, disp, mask)
     rng = np.random.default_rng(twojmax)
     beta = jnp.asarray(rng.normal(size=cfg.ncoeff))
@@ -128,6 +151,27 @@ def test_snap_y_kernel_parity(twojmax, dtype, layout):
                                atol=tol)
     np.testing.assert_allclose(y_k.imag / scale, y_ref.imag / scale,
                                atol=tol)
+
+
+def test_snap_y_half_kernel_vmap_matches_solo():
+    """A vmapped batch of half Y calls (``ForceServer``'s buckets) equals
+    the solo calls bit for bit."""
+    twojmax, natoms_pad = 4, 256
+    nh = build_index(twojmax).idxu_half_max
+    rng = np.random.default_rng(3)
+    ur, ui = (jnp.asarray(rng.normal(size=(3, nh, natoms_pad)))
+              for _ in range(2))
+    beta = jnp.asarray(rng.normal(size=SnapConfig(twojmax=twojmax).ncoeff))
+    coef = snap_y.y_coef_half(beta, twojmax)
+
+    def y(a, b):
+        return snap_y.snap_y_half_pallas(a, b, coef, twojmax=twojmax,
+                                         interpret=True)
+    batch = jax.vmap(y)(ur, ui)
+    for i in range(3):
+        for got, want in zip(batch, y(ur[i], ui[i])):
+            np.testing.assert_array_equal(np.asarray(got[i]),
+                                          np.asarray(want))
 
 
 def test_snap_y_kernel_tile_sweep():
@@ -189,9 +233,10 @@ def test_kernel_pipeline_matches_adjoint(twojmax, layout):
 
 
 def test_kernel_pipeline_mxu_bf16():
-    """bf16 MXU-feed policy: Y matmul operands in bfloat16, accumulation
-    in f32 — forces within 1e-2 relative of the fp64 adjoint, energy too
-    (the acceptance bar for the low-precision knob)."""
+    """bf16 feed policy: the Y contraction's U rows, coefficients and
+    products rounded to bfloat16, accumulation in f32 — forces within
+    1e-2 relative of the fp64 adjoint, energy too (the acceptance bar for
+    the low-precision knob), and further off than the f32 pipeline."""
     cfg = SnapConfig(twojmax=8, rcut=3.0)
     _, disp, nbr_idx, mask, _ = make_cluster(natoms=12, nnbor=8, seed=8)
     rng = np.random.default_rng(1)
@@ -207,6 +252,11 @@ def test_kernel_pipeline_mxu_bf16():
     assert rel < 1e-2, rel
     assert abs(float(e_b) - float(e_ref)) < 1e-2 * abs(float(e_ref)), \
         (float(e_b), float(e_ref))
+    _, _, f_32 = energy_forces_kernel(cfg, beta, 0.2, dx, dy, dz, nbr_idx,
+                                      mask, dtype=jnp.float32,
+                                      interpret=True)
+    rel_32 = float(jnp.abs(f_32 - f_ref).max() / jnp.abs(f_ref).max())
+    assert rel > rel_32, (rel, rel_32)
 
 
 @pytest.mark.parametrize('dtype,tol', [(jnp.float32, 1e-5),

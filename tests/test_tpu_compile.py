@@ -23,8 +23,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.indices import build_index
 from repro.kernels.snap_fused_de_half import snap_fused_de_half_pallas
 from repro.kernels.snap_u import snap_u_half_pallas
-from repro.kernels.snap_y import (Y_TILE, _y_coo_tiles, _y_half_coo_tiles,
-                                  snap_y_half_pallas, snap_y_pallas)
+from repro.kernels.snap_y import (Y_HALF_TILE, Y_TILE, _y_coo_tiles,
+                                  _y_half_coo_tiles, snap_y_half_pallas,
+                                  snap_y_pallas)
 
 NATOMS_PAD, NNBOR = 2048, 26
 GEO = dict(rcut=4.7, rmin0=0.0, rfac0=0.99363, switch_flag=True,
@@ -65,13 +66,13 @@ def compile_for(one_chip, fn, *shapes):
     return text.count('custom_call_target="tpu_custom_call"')
 
 
-def kernel_case(name, twojmax):
+def kernel_case(name, twojmax, natoms_pad=NATOMS_PAD):
     """(fn, shapes) of one kernel at the paper's sizes: half layout, except
     ``y_full`` (the full-plane A/B Y kernel)."""
     f32 = jnp.float32
     nh = build_index(twojmax).idxu_half_max
-    disp = ((NNBOR, 4, NATOMS_PAD), f32)
-    plane = ((nh, NATOMS_PAD), f32)
+    disp = ((NNBOR, 4, natoms_pad), f32)
+    plane = ((nh, natoms_pad), f32)
     if name == 'u':
         return (lambda d: snap_u_half_pallas(d, twojmax=twojmax, **GEO),
                 [disp])
@@ -84,11 +85,11 @@ def kernel_case(name, twojmax):
         return (lambda ur, ui, c: snap_y_pallas(
             ur, ui, c, twojmax=twojmax, interpret=False),
             [((nu, NATOMS_PAD), f32)] * 2 + [((ntiles, 1, Y_TILE), f32)])
-    ntiles = _y_half_coo_tiles(twojmax, Y_TILE)[0].shape[0]
+    ntiles = _y_half_coo_tiles(twojmax, Y_HALF_TILE)[0].shape[0]
     mxu = jnp.bfloat16 if name == 'y_bf16' else None
     return (lambda ur, ui, c: snap_y_half_pallas(
         ur, ui, c, twojmax=twojmax, mxu_dtype=mxu, interpret=False),
-        [plane, plane, ((ntiles, 1, Y_TILE), f32)])
+        [plane, plane, ((ntiles, 1, Y_HALF_TILE), f32)])
 
 
 @pytest.mark.parametrize('name,twojmax', [
@@ -100,6 +101,14 @@ def kernel_case(name, twojmax):
 ])
 def test_kernel_compiles_for_v5e(one_chip, name, twojmax):
     fn, shapes = kernel_case(name, twojmax)
+    assert compile_for(one_chip, fn, *shapes) == 1
+
+
+@pytest.mark.parametrize('name', ['y_f32', 'y_bf16'])
+def test_y_walk_compiles_for_md_box(one_chip, name):
+    """The half Y walk at the MD cell's 2J=8 box, 16,000 atoms: 125 lane
+    tiles in blocks of 32, the last one partial."""
+    fn, shapes = kernel_case(name, 8, natoms_pad=16000)
     assert compile_for(one_chip, fn, *shapes) == 1
 
 
@@ -139,3 +148,20 @@ def test_force_pipeline_kernels_carry_their_names(force_pipeline_hlo):
         assert len([n for n in names
                     if re.fullmatch(rf'{kernel}\.\d+', n)]) == 1, names
     assert len(names) == len(kernels)
+
+
+def test_y_kernel_keeps_its_plane_interface(force_pipeline_hlo):
+    """Y's custom call gives two f32[H,N] planes and takes U's f32[H,N]
+    planes among its operands: the interface by which a profile's reader
+    tells Y from the other kernels."""
+    line, = [ln for ln in force_pipeline_hlo.splitlines()
+             if re.search(r'%snap_y_half\.\d+ = ', ln)
+             and 'tpu_custom_call' in ln]
+    out = re.search(r'= \(f32\[([\d,]+)\]\S*, f32\[\1\]\S*\) custom-call\(',
+                    line)
+    assert out, line[:300]
+    hn = out.group(1)
+    assert hn == f'{build_index(8).idxu_half_max},{NATOMS_PAD}', hn
+    operands = re.search(
+        r'operand_layout_constraints=\{((?:[^{}]|\{[^{}]*\})*)\}', line)
+    assert operands.group(1).count(f'f32[{hn}]') == 2, operands.group(1)
