@@ -1,0 +1,16 @@
+"""Share of its roofline of the Y kernel of the species path
+(``kernels/snap_y.py``, ``snap_y_species``): the adjoint Y's operations
+and bytes (``counts_species``) over the kernel's device time in the
+trace, found by the name the program gives it (``named.py``)."""
+import counts_species
+
+UNIT = '%'
+LAYER = 'kernel snap_y_species'
+MOVES = 'katom_steps_per_s'
+SOURCE = 'device_trace'
+BETTER = 'higher'
+WORKLOADS = ['md_wbe_2j8_bcc16k']
+
+
+def read(ctx):
+    return counts_species.kernel_roofline(ctx, 'snap_y_species', 'y')

@@ -33,10 +33,16 @@ def plane_spec(rows):
     return pl.BlockSpec((rows, LANES), lambda i, *_: (I0, i))
 
 
-def pair_spec(nnbor):
-    """Block of a ``[nnbor, 4, natoms_pad]`` per-pair array: lane tile
-    ``i``, every neighbor row."""
-    return pl.BlockSpec((nnbor, 4, LANES), lambda i: (I0, I0, i))
+def pair_spec(nnbor, channels=4):
+    """Block of a ``[nnbor, channels, natoms_pad]`` per-pair array: lane
+    tile ``i``, every neighbor row."""
+    return pl.BlockSpec((nnbor, channels, LANES), lambda i: (I0, I0, i))
+
+
+# channels of the species path's per-pair array: (x, y, z, w, rcut) — the
+# mask channel carries the neighbour's element weight (0 off the pair set)
+# and a fifth channel the pair's own cutoff
+SPECIES_CHANNELS = 5
 
 
 @lru_cache(maxsize=8)

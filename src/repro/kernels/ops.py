@@ -23,13 +23,21 @@ pipeline alive for A/B benchmarking (see benchmarks/b_kernels.py).
 operands: ``jnp.bfloat16`` rounds its U rows, coefficients and products
 to bfloat16, with f32 accumulation.
 
+Multi-element SNAP (``cfg.species_path``, half layout only) runs its own
+three kernels, ``snap_u_species``, ``snap_y_species`` and
+``snap_de_species``: the per-pair array gains a fifth channel (the pair's
+cutoff) and carries the neighbour's element weight in its mask channel,
+and Y selects each lane's coefficients from one table per element.  The
+single-element path keeps its kernels, operands and shapes.
+
 Names in a profile: each kernel's ``pallas_call`` is named (``snap_u_half``,
 ``snap_y_half``, ``snap_fused_de_half``; ``snap_u``, ``snap_y``,
-``snap_fused_de`` in the full layout), which names its custom-call
-instruction in the optimized HLO.  The glue between them runs under the
-named scopes ``snap.layout``, ``snap.self_planes``, ``snap.y_coef``,
-``snap.assemble`` and ``snap.energy``, which reach each op's ``op_name``
-(the profiler's ``tf_op``).
+``snap_fused_de`` in the full layout; the species kernels above), which
+names its custom-call instruction in the optimized HLO.  The glue between
+them runs under the named scopes ``snap.layout``, ``snap.self_planes``,
+``snap.y_coef``, ``snap.assemble``, ``snap.energy`` and (species path)
+``snap.species``, which reach each op's ``op_name`` (the profiler's
+``tf_op``).
 """
 
 from __future__ import annotations
@@ -40,14 +48,17 @@ import numpy as np
 
 from repro.analysis.retrace import record_trace
 from repro.core.geometry import sanitize_displacements
-from repro.core.snap import SnapConfig, assemble_forces, bzero_shift
+from repro.core.snap import (SnapConfig, _require_species, assemble_forces,
+                             bzero_shift, species_coefficients,
+                             species_pairs)
 
 from .common import LANES
 from .snap_fused_de import snap_fused_de_pallas
-from .snap_fused_de_half import snap_fused_de_half_pallas
-from .snap_u import snap_u_half_pallas, snap_u_pallas
+from .snap_fused_de_half import (snap_de_species_pallas,
+                                 snap_fused_de_half_pallas)
+from .snap_u import snap_u_half_pallas, snap_u_pallas, snap_u_species_pallas
 from .snap_y import (Y_HALF_TILE, Y_TILE, snap_y_half_pallas, snap_y_pallas,
-                     y_coef, y_coef_half)
+                     snap_y_species_pallas, y_coef, y_coef_half)
 
 LAYOUTS = ('half', 'full')
 
@@ -65,6 +76,28 @@ def _kernel_layout(cfg: SnapConfig, dx, dy, dz, mask, dtype):
     m = disp[:, 3, :]
     disp = disp.at[:, 0, :].set(
         jnp.where(m > 0, disp[:, 0, :], 0.5 * cfg.rcut))
+    return disp, ok, natoms
+
+
+def _species_layout(cfg: SnapConfig, dx, dy, dz, mask, w_j, rc, dtype):
+    """Species path: [natoms, nnbor] -> [nnbor, 5, natoms_pad] rows (x, y,
+    z, w_j, rcut_ij).  Each slot is cut at its own pair's cutoff; the
+    weight channel is 0 off the pair set, and every slot off it (and
+    every dead lane) gets the regular radius and cutoff of the
+    single-element layout."""
+    mask = mask & (dx * dx + dy * dy + dz * dz < rc * rc)
+    dx, dy, dz, ok = sanitize_displacements(dx, dy, dz, mask,
+                                            safe_r=0.5 * cfg.rcut)
+    natoms = dx.shape[0]
+    pad = (-natoms) % LANES
+    w = jnp.where(ok, w_j, 0.0)
+    rc = jnp.where(ok, rc, cfg.rcut)
+    disp = jnp.stack([dx.T, dy.T, dz.T, w.T, rc.T], axis=1)
+    disp = jnp.pad(disp, [(0, 0), (0, 0), (0, pad)]).astype(dtype)
+    live = jnp.pad(ok.T, [(0, 0), (0, pad)])
+    disp = disp.at[:, 0, :].set(
+        jnp.where(live, disp[:, 0, :], 0.5 * cfg.rcut))
+    disp = disp.at[:, 4, :].set(jnp.where(live, disp[:, 4, :], cfg.rcut))
     return disp, ok, natoms
 
 
@@ -92,7 +125,7 @@ def half_planes_to_full(cfg: SnapConfig, h_r, h_i):
 
 
 def energy_from_ylist_lanes(cfg: SnapConfig, ut_r, ut_i, y_r, y_i,
-                            beta, beta0):
+                            beta, beta0, species=None):
     """Per-atom energy in kernel layout: (2/3) sum_jju w Re(conj(U) Y).
 
     Operands are [idxu_max, natoms_pad] or [idxu_half_max, natoms_pad]
@@ -100,12 +133,18 @@ def energy_from_ylist_lanes(cfg: SnapConfig, ut_r, ut_i, y_r, y_i,
     axis so the energy never leaves the kernel layout.  The half form is
     exact because ``dedr_weight`` is zero on every mirrored row.  Mirrors
     :func:`repro.core.snap.energy_from_ylist` exactly.
+
+    species: [natoms_pad] element index per lane (species path), with
+    ``beta`` [nelements, ncoeff] and ``beta0`` [nelements].
     """
     idx = cfg.index
     w = (idx.dedr_weight_half if ut_r.shape[0] == idx.idxu_half_max
          else idx.dedr_weight)
     w = jnp.asarray(w, ut_r.dtype)[:, None]
     e_raw = (2.0 / 3.0) * jnp.sum(w * (ut_r * y_r + ut_i * y_i), axis=0)
+    if species is not None:
+        shift = beta0 - bzero_shift(cfg, beta, e_raw.dtype)
+        return e_raw + jnp.asarray(shift, e_raw.dtype)[species]
     return beta0 + e_raw - bzero_shift(cfg, beta, e_raw.dtype)
 
 
@@ -113,7 +152,7 @@ def snap_force_pipeline(cfg: SnapConfig, beta, beta0, dx, dy, dz, nbr_idx,
                         mask, dtype=jnp.float32, interpret=None,
                         with_energy=True, layout: str = 'half',
                         y_tile: int | None = None, mxu_dtype=None,
-                        shard=None):
+                        shard=None, species=None):
     """Zero-relayout kernel pipeline: Pallas U -> Pallas Y -> Pallas fused dE.
 
     Every inter-stage tensor stays in the canonical [*, natoms_pad] device
@@ -137,7 +176,17 @@ def snap_force_pipeline(cfg: SnapConfig, beta, beta0, dx, dy, dz, nbr_idx,
     shard: optional ``(axis_name, n_shards)`` for the atom-sharded path —
     the Pallas stages are untouched (atoms already live on the lane axis,
     per shard), only the exit force assembly reduce-scatters.
+
+    species: the element index of every atom (global under ``shard``);
+    a multi-element config runs the species kernels on it (half layout).
     """
+    if _require_species(cfg, species):
+        if layout != 'half':
+            raise ValueError("the species path runs the half layout only")
+        return _species_pipeline(cfg, beta, beta0, dx, dy, dz, nbr_idx,
+                                 mask, species, dtype, interpret,
+                                 with_energy, y_tile or Y_HALF_TILE,
+                                 mxu_dtype, shard)
     if layout not in LAYOUTS:
         raise ValueError(f'unknown layout {layout!r}; choose from {LAYOUTS}')
     if mxu_dtype is not None and layout != 'half':
@@ -183,6 +232,45 @@ def snap_force_pipeline(cfg: SnapConfig, beta, beta0, dx, dy, dz, nbr_idx,
     with jax.named_scope('snap.energy'):
         e_atom = energy_from_ylist_lanes(cfg, ut_r, ut_i, y_r, y_i,
                                          beta, beta0)[:natoms]
+    return jnp.sum(e_atom), e_atom, forces
+
+
+def _species_pipeline(cfg: SnapConfig, beta, beta0, dx, dy, dz, nbr_idx,
+                      mask, species, dtype, interpret, with_energy, y_tile,
+                      mxu_dtype, shard):
+    """The species path of :func:`snap_force_pipeline` (half layout):
+    per-pair weight and cutoff ride in the per-pair array, per-element
+    coefficients in Y's tables, and each lane's element in a plane."""
+    natoms = dx.shape[0]
+    with jax.named_scope('snap.species'):
+        sp_i, w_j, rc = species_pairs(cfg, species, nbr_idx, shard)
+        beta, beta0 = species_coefficients(cfg, beta, beta0)
+        sp_lanes = jnp.pad(sp_i, (0, (-natoms) % LANES))
+    with jax.named_scope('snap.layout'):
+        disp, ok, _ = _species_layout(cfg, dx, dy, dz, mask, w_j, rc, dtype)
+    geo = dict(twojmax=cfg.twojmax, rmin0=cfg.rmin0, rfac0=cfg.rfac0,
+               switch_flag=cfg.switch_flag, interpret=interpret)
+    ut_r, ut_i = snap_u_species_pallas(disp, **geo)
+    with jax.named_scope('snap.self_planes'):
+        ut_r = ut_r + _self_planes(cfg, dtype, 'half')
+    with jax.named_scope('snap.y_coef'):
+        coef = y_coef_half(beta, cfg.twojmax, y_tile).astype(dtype)
+    y_r, y_i = snap_y_species_pallas(ut_r, ut_i, coef, sp_lanes,
+                                     twojmax=cfg.twojmax, tile=y_tile,
+                                     mxu_dtype=mxu_dtype,
+                                     interpret=interpret)
+    dedr = snap_de_species_pallas(disp, y_r, y_i, **geo)
+
+    axis_name, n_shards = shard if shard is not None else (None, 1)
+    with jax.named_scope('snap.assemble'):
+        dedr_pairs = dedr[:, :3, :natoms].transpose(2, 0, 1)
+        forces = assemble_forces(dedr_pairs, nbr_idx, ok, natoms * n_shards,
+                                 axis_name=axis_name)
+    if not with_energy:
+        return None, None, forces
+    with jax.named_scope('snap.energy'):
+        e_atom = energy_from_ylist_lanes(cfg, ut_r, ut_i, y_r, y_i, beta,
+                                         beta0, sp_lanes)[:natoms]
     return jnp.sum(e_atom), e_atom, forces
 
 

@@ -5,6 +5,10 @@ input layout, same outputs) but is built from the independently-validated
 :mod:`repro.core` reference pipeline — itself cross-checked against
 reverse-mode autodiff.  Kernel tests sweep shapes/dtypes and assert_allclose
 against these.
+
+The species kernels take the five-channel per-pair array (x, y, z, w_j,
+rcut_ij); the oracles take it too, with ``rcut=None``: each pair's cutoff
+is then its fifth channel and its weight the mask channel.
 """
 
 from __future__ import annotations
@@ -20,7 +24,10 @@ from repro.core.ulist import compute_dulist, compute_ulist
 
 def _geom_from_disp(disp, rcut, rmin0, rfac0, switch_flag, grad):
     """disp: [nnbor, 4, natoms] kernel layout -> per-pair geometry
-    [natoms, nnbor] with masked sfac/dsfac."""
+    [natoms, nnbor] with masked sfac/dsfac; with ``rcut=None`` disp is
+    [nnbor, 5, natoms] and channel 4 is each pair's cutoff."""
+    if rcut is None:
+        rcut = disp[:, 4, :].T
     x = disp[:, 0, :].T
     y = disp[:, 1, :].T
     z = disp[:, 2, :].T
@@ -35,9 +42,10 @@ def _geom_from_disp(disp, rcut, rmin0, rfac0, switch_flag, grad):
     return geom, dgeom
 
 
-def ref_snap_u(disp, *, twojmax, rcut, rmin0=0.0, rfac0=0.99363,
+def ref_snap_u(disp, *, twojmax, rcut=None, rmin0=0.0, rfac0=0.99363,
                switch_flag=True):
-    """Oracle for snap_u_pallas: [nnbor,4,N] -> (ut_r, ut_i) [idxu, N]."""
+    """Oracle for snap_u_pallas: [nnbor,4,N] -> (ut_r, ut_i) [idxu, N]
+    (and for snap_u_species_pallas, full planes, with ``rcut=None``)."""
     idx = build_index(twojmax)
     dtype = disp.dtype
     geom, _ = _geom_from_disp(disp, rcut, rmin0, rfac0, switch_flag, False)
@@ -46,11 +54,12 @@ def ref_snap_u(disp, *, twojmax, rcut, rmin0=0.0, rfac0=0.99363,
     return tot.real.T.astype(dtype), tot.imag.T.astype(dtype)
 
 
-def ref_snap_fused_de(disp, y_r, y_i, *, twojmax, rcut, rmin0=0.0,
+def ref_snap_fused_de(disp, y_r, y_i, *, twojmax, rcut=None, rmin0=0.0,
                       rfac0=0.99363, switch_flag=True):
     """Oracle for snap_fused_de_pallas.
 
-    disp: [nnbor, 4, N]; y_*: [idxu, N].  Returns [nnbor, 4, N].
+    disp: [nnbor, 4, N] (or [nnbor, 5, N] with ``rcut=None``, the species
+    kernel's); y_*: [idxu, N].  Returns [nnbor, 4, N].
     """
     idx = build_index(twojmax)
     dtype = disp.dtype

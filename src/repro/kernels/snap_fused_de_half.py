@@ -19,6 +19,11 @@ Counted effects vs v1 (per neighbor, 2J=8):
   - mirror transform ops:            ~480 -> ~60  (8x fewer)
   - VMEM live planes (u + 3 du):     2*(J+1)^2*4 -> ~half
   - Y planes streamed from HBM:      285 -> 155 rows (1.84x less traffic)
+
+``snap_de_species_pallas`` is the same kernel on the species path's
+five-channel per-pair array (x, y, z, w_j, rcut_ij): the pair's own
+cutoff in the geometry, and ``w_j`` scaling the switching value and its
+derivative (LAMMPS ``compute_duidrj``'s ``sfac *= wj``).
 """
 
 from __future__ import annotations
@@ -30,9 +35,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.indices import build_index
-from .common import (LANES, conj_mul, for_each_neighbor, geom_ck_grad,
-                     half_prev_rows, level_coefs, level_stitch, pair_spec,
-                     plane_spec, resolve_interpret)
+from .common import (LANES, SPECIES_CHANNELS, conj_mul, for_each_neighbor,
+                     geom_ck_grad, half_prev_rows, level_coefs, level_stitch,
+                     pair_spec, plane_spec, resolve_interpret)
 
 
 def _cm_add(x, y):
@@ -80,8 +85,9 @@ def _fused_de_half_kernel(disp_ref, y_r_ref, y_i_ref, out_ref, *, twojmax,
         y = disp_ref[k, 1, :]
         z = disp_ref[k, 2, :]
         m = disp_ref[k, 3, :]
+        rc = disp_ref[k, 4, :] if rcut is None else rcut
         (a_r, a_i, b_r, b_i, sfac), (da_r, da_i, db_r, db_i, dsfac) = \
-            geom_ck_grad(x, y, z, rcut, rmin0, rfac0, switch_flag)
+            geom_ck_grad(x, y, z, rc, rmin0, rfac0, switch_flag)
         sfac = sfac * m
         dsfac = [d * m for d in dsfac]
 
@@ -127,14 +133,11 @@ def _fused_de_half_kernel(disp_ref, y_r_ref, y_i_ref, out_ref, *, twojmax,
     for_each_neighbor(nnbor, neighbor)
 
 
-def snap_fused_de_half_pallas(disp, y_r, y_i, *, twojmax, rcut, rmin0=0.0,
-                              rfac0=0.99363, switch_flag=True,
-                              interpret=None):
-    """Same contract as snap_fused_de_pallas, except ``y_r``/``y_i`` are
-    **half planes** ``[idxu_half_max, natoms_pad]`` (the native output of
-    the half-plane Y kernel); recursion state is half-plane throughout."""
-    nnbor, four, natoms_pad = disp.shape
-    assert four == 4 and natoms_pad % LANES == 0
+def _de_half_call(name, disp, y_r, y_i, twojmax, rcut, rmin0, rfac0,
+                  switch_flag, interpret):
+    nnbor, channels, natoms_pad = disp.shape
+    assert channels == (4 if rcut is not None else SPECIES_CHANNELS)
+    assert natoms_pad % LANES == 0
     idx = build_index(twojmax)
     assert y_r.shape == (idx.idxu_half_max, natoms_pad), y_r.shape
     dtype = disp.dtype
@@ -146,9 +149,29 @@ def snap_fused_de_half_pallas(disp, y_r, y_i, *, twojmax, rcut, rmin0=0.0,
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pair_spec(nnbor), plane_spec(nh), plane_spec(nh)],
+        in_specs=[pair_spec(nnbor, channels), plane_spec(nh),
+                  plane_spec(nh)],
         out_specs=pair_spec(nnbor),
         out_shape=jax.ShapeDtypeStruct((nnbor, 4, natoms_pad), dtype),
         interpret=resolve_interpret(interpret),
-        name='snap_fused_de_half',
+        name=name,
     )(disp, y_r, y_i)
+
+
+def snap_fused_de_half_pallas(disp, y_r, y_i, *, twojmax, rcut, rmin0=0.0,
+                              rfac0=0.99363, switch_flag=True,
+                              interpret=None):
+    """Same contract as snap_fused_de_pallas, except ``y_r``/``y_i`` are
+    **half planes** ``[idxu_half_max, natoms_pad]`` (the native output of
+    the half-plane Y kernel); recursion state is half-plane throughout."""
+    return _de_half_call('snap_fused_de_half', disp, y_r, y_i, twojmax,
+                         rcut, rmin0, rfac0, switch_flag, interpret)
+
+
+def snap_de_species_pallas(disp, y_r, y_i, *, twojmax, rmin0=0.0,
+                           rfac0=0.99363, switch_flag=True, interpret=None):
+    """Fused dE of the species path: ``disp`` is [nnbor, 5, natoms_pad]
+    (x, y, z, w_j, rcut_ij); otherwise :func:`snap_fused_de_half_pallas`'s
+    contract.  Returns [nnbor, 4, natoms_pad] (dE/dr x, y, z, 0)."""
+    return _de_half_call('snap_de_species', disp, y_r, y_i, twojmax, None,
+                         rmin0, rfac0, switch_flag, interpret)

@@ -21,6 +21,11 @@ recursion carries only the symmetric left rows 2mb <= j and the output
 planes are ``[idxu_half_max, natoms_pad]`` (652 vs 1240 rows at 2J=14) —
 the mirror fill disappears from the per-level step entirely and the
 emitted HBM plane traffic drops ~1.9x.
+
+``snap_u_species_pallas`` is the half-plane kernel of multi-element SNAP:
+its per-pair array has a fifth channel, the pair's cutoff, which the
+geometry reads per lane in place of the compiled-in scalar, and its mask
+channel carries the neighbour's element weight.
 """
 
 from __future__ import annotations
@@ -32,13 +37,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.indices import build_index
-from .common import (LANES, for_each_neighbor, geom_ck, pair_spec, plane_spec,
-                     resolve_interpret, u_half_level_step, u_level_step)
+from .common import (LANES, SPECIES_CHANNELS, for_each_neighbor, geom_ck,
+                     pair_spec, plane_spec, resolve_interpret,
+                     u_half_level_step, u_level_step)
 
 
 def _snap_u_kernel(disp_ref, out_r_ref, out_i_ref, *, level_step, blocks,
                    twojmax, nnbor, rcut, rmin0, rfac0, switch_flag, dtype):
-    """disp_ref: [nnbor, 4, LANES] rows (x, y, z, mask) — atoms on lanes.
+    """disp_ref: [nnbor, 4, LANES] rows (x, y, z, mask) — atoms on lanes;
+    with ``rcut=None`` (species path) [nnbor, 5, LANES] rows (x, y, z,
+    weight, rcut).
     out_*_ref: [rows, LANES] accumulated sum_k sfac_k * U_k (no self).
 
     ``level_step`` advances the recursion one level (full planes or left
@@ -55,8 +63,9 @@ def _snap_u_kernel(disp_ref, out_r_ref, out_i_ref, *, level_step, blocks,
         y = disp_ref[k, 1, :]
         z = disp_ref[k, 2, :]
         m = disp_ref[k, 3, :]
+        rc = disp_ref[k, 4, :] if rcut is None else rcut
         a_r, a_i, b_r, b_i, sfac = geom_ck(
-            x, y, z, rcut, rmin0, rfac0, switch_flag)
+            x, y, z, rc, rmin0, rfac0, switch_flag)
         sfac = sfac * m
         out_r_ref[0:1, :] += sfac[None, :]
         lvl_r = jnp.ones((1, 1, LANES), dtype)
@@ -74,8 +83,9 @@ def _snap_u_kernel(disp_ref, out_r_ref, out_i_ref, *, level_step, blocks,
 
 def _u_call(name, disp, rows, level_step, blocks, twojmax, rcut, rmin0,
             rfac0, switch_flag, interpret):
-    nnbor, four, natoms_pad = disp.shape
-    assert four == 4 and natoms_pad % LANES == 0
+    nnbor, channels, natoms_pad = disp.shape
+    assert channels == (4 if rcut is not None else SPECIES_CHANNELS)
+    assert natoms_pad % LANES == 0
     dtype = disp.dtype
     kernel = partial(
         _snap_u_kernel, level_step=level_step, blocks=blocks,
@@ -85,7 +95,7 @@ def _u_call(name, disp, rows, level_step, blocks, twojmax, rcut, rmin0,
     return pl.pallas_call(
         kernel,
         grid=(natoms_pad // LANES,),
-        in_specs=[pair_spec(nnbor)],
+        in_specs=[pair_spec(nnbor, channels)],
         out_specs=[plane_spec(rows), plane_spec(rows)],
         out_shape=[plane, plane],
         interpret=resolve_interpret(interpret),
@@ -117,4 +127,17 @@ def snap_u_half_pallas(disp, *, twojmax, rcut, rmin0=0.0, rfac0=0.99363,
     idx = build_index(twojmax)
     return _u_call('snap_u_half', disp, idx.idxu_half_max,
                    u_half_level_step, idx.idxu_half_block, twojmax, rcut,
+                   rmin0, rfac0, switch_flag, interpret)
+
+
+def snap_u_species_pallas(disp, *, twojmax, rmin0=0.0, rfac0=0.99363,
+                          switch_flag=True, interpret=None):
+    """Half-plane U of the species path: ``disp`` is [nnbor, 5,
+    natoms_pad] (x, y, z, w_j, rcut_ij) with ``w_j`` 0 on every slot off
+    the pair set.  Each pair's theta0 and switching function use its own
+    cutoff, and its switching value is scaled by ``w_j``; the output is
+    :func:`snap_u_half_pallas`'s."""
+    idx = build_index(twojmax)
+    return _u_call('snap_u_species', disp, idx.idxu_half_max,
+                   u_half_level_step, idx.idxu_half_block, twojmax, None,
                    rmin0, rfac0, switch_flag, interpret)

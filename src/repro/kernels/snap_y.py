@@ -25,6 +25,10 @@ does no sign arithmetic.  The beta factor is a runtime [nnz] gather done
 once at the JAX level (no natoms axis), so the kernel is beta-agnostic
 and Z is never materialized — the paper's compute_Yi fusion.
 
+:func:`snap_y_species_pallas` (multi-element SNAP) is the same walk
+with one coefficient table per element and a plane of each lane's
+element: each entry's coefficient is selected per lane.
+
 The walk issues the algorithm's 10 flops per entry and atom.  It
 replaced a one-hot MXU contraction that issued 170-760x that work at
 the 2J=8 and 2J=14 sizes: Y 53.0 and 596 ms an evaluation on one v5e.
@@ -264,9 +268,8 @@ def _y_half_block(nh: int, natoms_pad: int, itemsize: int):
         most //= 2
 
 
-def _snap_y_half_kernel(fac1_ref, fac2_ref, dest_ref, coef_ref, ut_r_ref,
-                        ut_i_ref, y_r_ref, y_i_ref, u_s, y_s, *, nh,
-                        lane_tiles, rows, ngroups, ntiles, dtype, mxu_dtype):
+def _snap_y_half_kernel(*refs, nh, lane_tiles, rows, ngroups, ntiles,
+                        dtype, mxu_dtype, nspecies=0):
     """One (lane block, COO chunk) step of the sparse walk.
 
     Table refs are SMEM scalars of one chunk (offsets already scaled to
@@ -277,7 +280,21 @@ def _snap_y_half_kernel(fac1_ref, fac2_ref, dest_ref, coef_ref, ut_r_ref,
     (``u_s`` holds U, then conj U; ``y_s`` the Y accumulator).  The
     packing is a strided copy once per lane block; every entry then reads
     and writes whole vregs.
+
+    ``nspecies > 0`` (species path): one coefficient ref per element
+    after ``dest_ref``, and a ``[1, lane_tiles * LANES]`` element-index
+    block before the U planes, packed like one U row into ``sp_s``
+    (lane tile ``c`` at row ``c``); each entry's coefficient is selected
+    per lane from the element sets.
     """
+    if nspecies:
+        fac1_ref, fac2_ref, dest_ref = refs[:3]
+        coef_refs = refs[3:3 + nspecies]
+        (sp_ref, ut_r_ref, ut_i_ref, y_r_ref, y_i_ref, u_s, y_s,
+         sp_s) = refs[3 + nspecies:]
+    else:
+        (fac1_ref, fac2_ref, dest_ref, coef_ref, ut_r_ref, ut_i_ref,
+         y_r_ref, y_i_ref, u_s, y_s) = refs
     t = pl.program_id(1)
     step = 2 * rows
     rounding = jnp.dtype(mxu_dtype) != jnp.dtype(dtype)
@@ -298,10 +315,26 @@ def _snap_y_half_kernel(fac1_ref, fac2_ref, dest_ref, coef_ref, ut_r_ref,
             u_s[strided(nh * step + c), :] = u_r
             u_s[strided(nh * step + rows + c), :] = -u_i
         y_s[...] = jnp.zeros(y_s.shape, dtype)
+        if nspecies:
+            sp_s[...] = jnp.zeros(sp_s.shape, dtype)
+            for c in range(lane_tiles):
+                sp_s[pl.ds(c, 1), :] = sp_ref[:, pl.ds(c * LANES, LANES)]
 
     def factor(off):
         x = u_s[pl.ds(pl.multiple_of(off, step), step), :]
         return x[:rows], x[rows:]
+
+    if nspecies:
+        spv = sp_s[...]
+
+        def coefficient(k):
+            c = coef_refs[0][k]
+            for e in range(1, nspecies):
+                c = jnp.where(spv == e, coef_refs[e][k], c)
+            return c
+    else:
+        def coefficient(k):
+            return coef_ref[k]
 
     def group(g):
         acc_r = jnp.zeros((rows, LANES), dtype)
@@ -310,7 +343,7 @@ def _snap_y_half_kernel(fac1_ref, fac2_ref, dest_ref, coef_ref, ut_r_ref,
             k = g * Y_GROUP + e
             u1r, u1i = factor(fac1_ref[k])
             u2r, u2i = factor(fac2_ref[k])
-            c = coef_ref[k]
+            c = coefficient(k)
             acc_r = acc_r + c * rnd(u1r * u2r - u1i * u2i)
             acc_i = acc_i + c * rnd(u1r * u2i + u1i * u2r)
         d = pl.multiple_of(dest_ref[g], step)
@@ -342,6 +375,63 @@ def y_coef_half(beta, twojmax: int, tile: int = Y_HALF_TILE):
     return jnp.asarray(cg, beta.dtype) * betaj[..., jjz]
 
 
+def _y_half_call(name, ut_r, ut_i, coefs, species, twojmax, tile,
+                 mxu_dtype, interpret):
+    """The walk's ``pallas_call``: ``coefs`` is one coefficient table, or
+    (species path) a list of one per element with the element-index
+    plane ``species`` [1, natoms_pad]."""
+    idx = build_index(twojmax)
+    nh, natoms_pad = ut_r.shape
+    assert nh == idx.idxu_half_max and natoms_pad % LANES == 0
+    dtype = ut_r.dtype
+    mxu_dtype = jnp.dtype(mxu_dtype) if mxu_dtype is not None else dtype
+    lane_tiles, rows, vmem = _y_half_block(nh, natoms_pad,
+                                           jnp.dtype(dtype).itemsize)
+    fac1, fac2, dest = _y_half_offsets(twojmax, tile, 2 * rows)
+    nspecies = 0 if species is None else len(coefs)
+    coefs = [coefs] if species is None else list(coefs)
+    for c in coefs:
+        assert c.shape == fac1.shape, (c.shape, fac1.shape)
+    coefs = [c.astype(mxu_dtype).astype(dtype) for c in coefs]
+
+    ntiles, _, chunk = fac1.shape
+    kernel = partial(_snap_y_half_kernel, nh=nh, lane_tiles=lane_tiles,
+                     rows=rows, ngroups=chunk // Y_GROUP, ntiles=ntiles,
+                     dtype=dtype, mxu_dtype=mxu_dtype, nspecies=nspecies)
+    width = lane_tiles * LANES
+
+    def smem(n):
+        return pl.BlockSpec((None, None, n), lambda i, t: (t, I0, I0),
+                            memory_space=pltpu.SMEM)
+
+    def lanes(n):
+        return pl.BlockSpec((n, width), lambda i, t: (I0, i),
+                            pipeline_mode=pl.Buffered(1))
+    plane = lanes(nh)
+    in_specs = ([smem(chunk), smem(chunk), smem(chunk // Y_GROUP)]
+                + [smem(chunk)] * len(coefs) + [plane, plane])
+    operands = [jnp.asarray(fac1), jnp.asarray(fac2), jnp.asarray(dest),
+                *coefs, ut_r, ut_i]
+    scratch = [pltpu.VMEM((2 * nh * 2 * rows, LANES), dtype),
+               pltpu.VMEM((nh * 2 * rows, LANES), dtype)]
+    if nspecies:
+        in_specs.insert(-2, lanes(1))
+        operands.insert(-2, species.astype(dtype))
+        scratch.append(pltpu.VMEM((rows, LANES), dtype))
+    return pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(natoms_pad, width), ntiles),
+        in_specs=in_specs,
+        out_specs=[plane, plane],
+        out_shape=[jax.ShapeDtypeStruct((nh, natoms_pad), dtype)] * 2,
+        scratch_shapes=scratch,
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem + 16 * 1024 * 1024),
+        name=name,
+    )(*operands)
+
+
 def snap_y_half_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_HALF_TILE,
                        mxu_dtype=None, interpret=None):
     """ut_r/ut_i: [idxu_half_max, natoms_pad] half Ulisttot planes (self
@@ -357,40 +447,20 @@ def snap_y_half_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_HALF_TILE,
     and the complex products to bfloat16 before they are scaled and
     summed; accumulation stays in the plane dtype.
     """
-    idx = build_index(twojmax)
-    nh, natoms_pad = ut_r.shape
-    assert nh == idx.idxu_half_max and natoms_pad % LANES == 0
-    dtype = ut_r.dtype
-    mxu_dtype = jnp.dtype(mxu_dtype) if mxu_dtype is not None else dtype
-    lane_tiles, rows, vmem = _y_half_block(nh, natoms_pad,
-                                           jnp.dtype(dtype).itemsize)
-    fac1, fac2, dest = _y_half_offsets(twojmax, tile, 2 * rows)
-    assert coef.shape == fac1.shape, (coef.shape, fac1.shape)
-    coef = coef.astype(mxu_dtype).astype(dtype)
+    return _y_half_call('snap_y_half', ut_r, ut_i, coef, None, twojmax,
+                        tile, mxu_dtype, interpret)
 
-    ntiles, _, chunk = fac1.shape
-    kernel = partial(_snap_y_half_kernel, nh=nh, lane_tiles=lane_tiles,
-                     rows=rows, ngroups=chunk // Y_GROUP, ntiles=ntiles,
-                     dtype=dtype, mxu_dtype=mxu_dtype)
-    width = lane_tiles * LANES
 
-    def smem(n):
-        return pl.BlockSpec((None, None, n), lambda i, t: (t, I0, I0),
-                            memory_space=pltpu.SMEM)
-    plane = pl.BlockSpec((nh, width), lambda i, t: (I0, i),
-                         pipeline_mode=pl.Buffered(1))
-    return pl.pallas_call(
-        kernel,
-        grid=(pl.cdiv(natoms_pad, width), ntiles),
-        in_specs=[smem(chunk), smem(chunk), smem(chunk // Y_GROUP),
-                  smem(chunk), plane, plane],
-        out_specs=[plane, plane],
-        out_shape=[jax.ShapeDtypeStruct((nh, natoms_pad), dtype)] * 2,
-        scratch_shapes=[pltpu.VMEM((2 * nh * 2 * rows, LANES), dtype),
-                        pltpu.VMEM((nh * 2 * rows, LANES), dtype)],
-        interpret=resolve_interpret(interpret),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=vmem + 16 * 1024 * 1024),
-        name='snap_y_half',
-    )(jnp.asarray(fac1), jnp.asarray(fac2), jnp.asarray(dest), coef,
-      ut_r, ut_i)
+def snap_y_species_pallas(ut_r, ut_i, coef, species, *, twojmax,
+                          tile=Y_HALF_TILE, mxu_dtype=None, interpret=None):
+    """Half-plane Y of the species path: ``coef`` is [nelements, ntiles,
+    1, chunk] (:func:`y_coef_half` of a [nelements, ncoeff] beta) and
+    ``species`` [natoms_pad] the element index of every lane.  Each
+    lane's Y uses its own element's coefficients, selected per table
+    entry (one vector select on the walk's ~10 operations an entry);
+    otherwise :func:`snap_y_half_pallas`'s contract."""
+    natoms_pad = ut_r.shape[1]
+    assert species.shape == (natoms_pad,), species.shape
+    return _y_half_call('snap_y_species', ut_r, ut_i, list(coef),
+                        species.reshape(1, natoms_pad), twojmax, tile,
+                        mxu_dtype, interpret)

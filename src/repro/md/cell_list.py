@@ -12,8 +12,9 @@ Atoms are binned into a static ``[ncells, cell_cap]`` table by sorting atom
 indices by flat bin id (``argsort`` + ``searchsorted`` rank-within-bin, a
 device-friendly counting sort).  Candidates come from a **deduplicated**
 27-stencil gather (offsets collapse mod nbins, so boxes with < 3 bins along
-an axis never revisit a cell); packing valid pairs to the front of the
-padded ``[N, K]`` lists is a stable argsort over the candidate axis.
+an axis never revisit a cell), made once a cell and shared by its atoms;
+packing valid pairs to the front of the padded ``[N, K]`` lists is a
+stable sort of the candidates on their invalid flag.
 
 Overflow contract
 -----------------
@@ -156,6 +157,17 @@ def _bin_atoms(pos, box, grid: CellGrid):
     return table[:-1].reshape(grid.ncells, cap), b, counts.max()
 
 
+@lru_cache(maxsize=32)
+def _stencil_cells(grid: CellGrid) -> np.ndarray:
+    """[ncells, S] flat ids of every cell's stencil cells, in stencil
+    order (static: a function of the grid alone)."""
+    nb = np.asarray(grid.nbins)
+    b = np.stack(np.unravel_index(np.arange(grid.ncells), grid.nbins), 1)
+    cells = (b[:, None, :] + np.asarray(grid.stencil)[None]) % nb
+    return ((cells[..., 0] * nb[1] + cells[..., 1]) * nb[2]
+            + cells[..., 2]).astype(np.int32)
+
+
 def device_neighbors(pos, box, grid: CellGrid):
     """Fixed-shape neighbor build, entirely traced (no host sync).
 
@@ -167,35 +179,39 @@ def device_neighbors(pos, box, grid: CellGrid):
     ``shifts`` satisfy ``disp = pos[nbr_idx] + shifts - pos[:, None]``
     exactly for the *raw* (possibly unwrapped) positions, so the MD loop can
     recompute displacements on device as atoms drift out of the box.
+
+    Every atom of a cell has the same candidates, so they are gathered
+    once a cell (``[ncells, S*cap]`` slots) and handed to each atom as a
+    whole row; packing sorts the candidate indices along with the invalid
+    flag, and the kept pairs' shifts are recomputed from their positions.
+    Element gathers over all ``N * S * cap`` candidates, which a TPU pays
+    for one by one, would cost several times the rest of the build.
     """
     N = pos.shape[0]
     table, b, cell_max = _bin_atoms(pos, box, grid)
-    nb_flat = []
-    for off in grid.stencil:
-        nbn = jnp.mod(b + jnp.asarray(off, jnp.int32),
-                      jnp.asarray(grid.nbins, jnp.int32))
-        nb_flat.append((nbn[:, 0] * grid.nbins[1] + nbn[:, 1])
-                       * grid.nbins[2] + nbn[:, 2])
-    cells = jnp.stack(nb_flat, axis=1)              # [N, S]
-    cand = table[cells].reshape(N, -1)              # [N, S*cap]
+    flat = (b[:, 0] * grid.nbins[1] + b[:, 1]) * grid.nbins[2] + b[:, 2]
+    stencil = jnp.asarray(_stencil_cells(grid))           # [ncells, S]
     pos_pad = jnp.concatenate([pos, jnp.zeros((1, 3), pos.dtype)])
-    d = pos_pad[cand] - pos[:, None, :]
-    shift = -box * jnp.round(d / box)
+    cell_cand = table[stencil].reshape(grid.ncells, -1)   # [ncells, S*cap]
+    cell_pos = jnp.transpose(pos_pad[table][stencil], (0, 3, 1, 2)).reshape(
+        grid.ncells, 3, -1)                               # [ncells, 3, S*cap]
+    cand = cell_cand[flat]                                # [N, S*cap]
+    d = cell_pos[flat] - pos[:, :, None]                  # [N, 3, S*cap]
+    shift = -box[:, None] * jnp.round(d / box[:, None])
     dd = d + shift
-    r2 = jnp.sum(dd * dd, axis=-1)
+    r2 = jnp.sum(dd * dd, axis=1)
     rb2 = grid.rcut_build * grid.rcut_build
     valid = ((cand != jnp.arange(N, dtype=jnp.int32)[:, None])
              & (cand < N) & (r2 < rb2))
     counts = valid.sum(axis=1)
     # pack valid candidates to the front: stable sort on the invalid flag
-    key = jnp.logical_not(valid).astype(jnp.int32)
-    ordk = jnp.argsort(key, axis=1)[:, :grid.max_nbors]
-    mask = jnp.take_along_axis(valid, ordk, axis=1)
-    nbr_idx = jnp.where(mask, jnp.take_along_axis(cand, ordk, axis=1),
-                        0).astype(jnp.int32)
-    shifts = jnp.where(mask[..., None],
-                       jnp.take_along_axis(shift, ordk[..., None], axis=1),
-                       0.0)
+    key, packed = jax.lax.sort(
+        (jnp.logical_not(valid).astype(jnp.int32), cand), dimension=1,
+        is_stable=True, num_keys=1)
+    mask = key[:, :grid.max_nbors] == 0
+    nbr_idx = jnp.where(mask, packed[:, :grid.max_nbors], 0)
+    d = pos_pad[nbr_idx] - pos[:, None, :]
+    shifts = jnp.where(mask[..., None], -box * jnp.round(d / box), 0.0)
     flags = jnp.stack([counts.max().astype(jnp.int32),
                        cell_max.astype(jnp.int32)])
     return nbr_idx, mask, shifts, flags

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.retrace import record_trace
-from repro.core.snap import SnapConfig, energy_forces
+from repro.core.snap import SnapConfig, energy_forces, species_pairs
 from repro.runtime.trace import span
 from .cell_list import (FLAG_DRIFT, FLAG_ESCAPE, FLAG_NAN_FORCE,
                         FLAG_NAN_STATE, N_FLAGS, auto_cell_cap,
@@ -57,15 +57,51 @@ class MDState:
 
 
 def init_velocities(n, temp, mass=W_MASS, seed=0):
+    """Maxwell-Boltzmann velocities; ``mass`` a scalar or one per atom
+    (zero total momentum either way)."""
     rng = np.random.default_rng(seed)
-    sigma = np.sqrt(KB * temp / (mass / ACC_CONV))
-    v = rng.normal(scale=sigma, size=(n, 3))
+    m = np.asarray(mass, np.float64)
+    sigma = np.sqrt(KB * temp / (m / ACC_CONV))
+    if m.ndim:
+        sigma = sigma[:, None]
+    v = rng.normal(size=(n, 3)) * sigma
+    if m.ndim:
+        return v - (m[:, None] * v).sum(0) / m.sum()
     return v - v.mean(0)
 
 
 def temperature(vel, mass=W_MASS):
-    ke = 0.5 * (mass / ACC_CONV) * float(np.sum(vel * vel))
+    m = np.asarray(mass, np.float64)
+    m = m[:, None] if m.ndim else m
+    ke = 0.5 * float(np.sum(m * vel * vel)) / ACC_CONV
     return 2.0 * ke / (3.0 * len(vel) * KB), ke
+
+
+def _mass_terms(mass):
+    """(acc_scale, kinetic(vel)) of the integrators: ``mass`` a scalar,
+    or one per atom (a [N, 1] constant in the compiled step)."""
+    if np.ndim(mass) == 0:
+        def kinetic(vel):
+            return (0.5 * mass / ACC_CONV) * jnp.sum(vel * vel)
+        return ACC_CONV / mass, kinetic
+    m = jnp.asarray(np.asarray(mass, np.float64)[:, None])
+
+    def kinetic(vel):
+        return (0.5 / ACC_CONV) * jnp.sum(m * vel * vel)
+    return ACC_CONV / m, kinetic
+
+
+def species_pair_counts(cfg: SnapConfig, species, pos, nbr_idx, shifts,
+                        mask):
+    """[nelements, nelements] counts of the ordered pairs (i, j) inside
+    their own cutoff ``rcut_ij``, by (element of i, element of j)."""
+    sp_i, _, rc = species_pairs(cfg, species, nbr_idx)
+    disp = pos[nbr_idx] + shifts - pos[:, None, :]
+    inside = mask & (jnp.sum(disp * disp, -1) < rc * rc)
+    ne = cfg.nelements
+    code = sp_i[:, None] * ne + jnp.asarray(species, jnp.int32)[nbr_idx]
+    return jnp.zeros(ne * ne, jnp.int32).at[code.reshape(-1)].add(
+        inside.reshape(-1).astype(jnp.int32)).reshape(ne, ne)
 
 
 def make_force_fn(cfg: SnapConfig, beta, beta0, impl='adjoint', **kw):
@@ -86,7 +122,7 @@ def make_segment_fn(cfg: SnapConfig, beta, beta0, dt, mass,
     recomputed on device from the rebuild-time topology + image shifts (the
     same contract as the autodiff oracle's ``make_energy_fn``).
     """
-    acc_scale = ACC_CONV / mass
+    acc_scale, kinetic = _mass_terms(mass)
 
     @jax.jit
     def segment(pos, vel, f, nbr_idx, shifts, mask):
@@ -99,7 +135,7 @@ def make_segment_fn(cfg: SnapConfig, beta, beta0, dt, mass,
                 cfg, beta, beta0, disp[..., 0], disp[..., 1], disp[..., 2],
                 nbr_idx, mask, impl=impl, **kw)
             vel = vel + (0.5 * dt * acc_scale) * f_new
-            ke = (0.5 * mass / ACC_CONV) * jnp.sum(vel * vel)
+            ke = kinetic(vel)
             return (pos, vel, f_new), (e, ke)
 
         (pos, vel, f), (pe, ke) = jax.lax.scan(
@@ -143,7 +179,7 @@ def make_device_chunk_fn(cfg: SnapConfig, beta, beta0, dt, mass, grid,
     :func:`repro.kernels.ops.make_sharded_force_fn`; signature
     ``(dx, dy, dz, nbr_idx, mask) -> (e, e_atom, f)``.
     """
-    acc_scale = ACC_CONV / mass
+    acc_scale, kinetic = _mass_terms(mass)
     half_skin2 = (0.5 * grid.skin) ** 2
     rc2 = cfg.rcut * cfg.rcut
     counter = trace_counter if trace_counter is not None else {}
@@ -193,7 +229,7 @@ def make_device_chunk_fn(cfg: SnapConfig, beta, beta0, dt, mass, grid,
             e, f_new = eval_force(disp, nbr_idx, mask_t)
             with jax.named_scope('md.verlet'):
                 vel = vel + (0.5 * dt * acc_scale) * f_new
-                ke = (0.5 * mass / ACC_CONV) * jnp.sum(vel * vel)
+                ke = kinetic(vel)
             if guards:
                 with jax.named_scope('md.flags'):
                     # sticky health lattice: cheap O(N) reductions vs the
@@ -238,8 +274,16 @@ def run_nve(cfg: SnapConfig, beta, beta0, state: MDState, n_steps: int,
             fn_cache: Dict | None = None, skin: float = 1.0,
             cell_cap: int | None = None, shards: int = 1,
             policy=None, checkpoint_dir=None, checkpoint_every: int = 0,
-            restore: bool = False, fault_hook=None):
+            restore: bool = False, fault_hook=None, species=None):
     """NVE loop; returns (state, list of thermo dicts).
+
+    Multi-element SNAP: ``species`` is the element index of every atom
+    (static for the run), ``beta``/``beta0`` are per element (see
+    :mod:`repro.core.snap`), and ``mass`` may be one per atom.  Lists are
+    built at the largest pair cutoff (``cfg.rcut``) plus the skin; each
+    pair is cut at its own cutoff inside the force pipeline.  The device
+    loop then records ``fn_cache['species_pairs']``: the pairs inside
+    their cutoff by (element, element) at the last rebuild.
 
     loop='device' folds the neighbor rebuild into the jitted step scan (a
     half-skin displacement trigger decides rebuilds on device); the host
@@ -285,9 +329,11 @@ def run_nve(cfg: SnapConfig, beta, beta0, state: MDState, n_steps: int,
     fingerprint.
     """
     if fn_cache is not None:
-        fp = (cfg, np.asarray(beta).tobytes(), float(beta0), float(dt),
-              float(mass), impl, float(skin), int(shards),
-              tuple(sorted((force_kwargs or {}).items())))
+        fp = (cfg, np.asarray(beta).tobytes(), np.asarray(beta0).tobytes(),
+              float(dt), np.asarray(mass, np.float64).tobytes(), impl,
+              float(skin), int(shards),
+              tuple(sorted((force_kwargs or {}).items())),
+              None if species is None else np.asarray(species).tobytes())
         if fn_cache.setdefault('fingerprint', fp) != fp:
             raise ValueError(
                 'fn_cache was built for different physics parameters '
@@ -297,6 +343,12 @@ def run_nve(cfg: SnapConfig, beta, beta0, state: MDState, n_steps: int,
         raise ValueError(
             'policy/checkpoint/restore/fault_hook are device-loop '
             "features; use loop='device'")
+    if cfg.species_path:
+        if species is None or len(species) != len(state.pos):
+            raise ValueError('a multi-element config needs species, one '
+                             'element index per atom')
+        force_kwargs = dict(force_kwargs or {},
+                            species=np.asarray(species, np.int32))
     if loop == 'device':
         with span('md.run'):
             return _run_nve_device(cfg, beta, beta0, state, n_steps, dt,
@@ -521,7 +573,7 @@ def _run_nve_device(cfg, beta, beta0, state, n_steps, dt, mass, impl,
             # short runs
             e0, f = _seed_force(cache, cfg, beta, beta0, impl, kw, force_fn,
                                 pos, nbr_idx, shifts, mask)
-            ke0 = 0.5 * (mass / ACC_CONV) * float(jnp.sum(vel * vel))
+            ke0 = float(_mass_terms(mass)[1](vel))
             e_ref = float(e0) + ke0
             carry = dict(pos=pos, vel=vel, f=f, nbr_idx=nbr_idx,
                          shifts=shifts, mask=mask, pos_ref=pos,
@@ -627,6 +679,14 @@ def _run_nve_device(cfg, beta, beta0, state, n_steps, dt, mass, impl,
                                             dict(path=str(path))))
                 steps_since_ckpt = 0
     cache['device_rebuilds'] = rebuilds
+    if cfg.species_path:
+        count = cache.get('species_count')
+        if count is None:
+            count = cache['species_count'] = jax.jit(
+                partial(species_pair_counts, cfg, kw['species']))
+        cache['species_pairs'] = np.asarray(count(
+            carry['pos_ref'], carry['nbr_idx'], carry['shifts'],
+            carry['mask'])).tolist()
     state.pos = np.asarray(carry['pos'])
     state.vel = np.asarray(carry['vel'])
     state.step += n_steps
@@ -641,6 +701,8 @@ def _run_nve_host(cfg, beta, beta0, state, n_steps, dt, mass, impl,
         cache['force'] = make_force_fn(cfg, beta, beta0, impl,
                                        **(force_kwargs or {}))
     force_fn = cache['force']
+    m = np.asarray(mass, np.float64)
+    m = m[:, None] if m.ndim else m
     thermo = []
     nbr = None
     f = None
@@ -656,7 +718,7 @@ def _run_nve_host(cfg, beta, beta0, state, n_steps, dt, mass, impl,
                                  nbr_idx, mask)
                 f = np.asarray(fj)
         # velocity verlet
-        acc = f / mass * ACC_CONV
+        acc = f / m * ACC_CONV
         state.vel = state.vel + 0.5 * dt * acc
         state.pos = state.pos + dt * state.vel
         nbr_idx, mask = nbr
@@ -664,7 +726,7 @@ def _run_nve_host(cfg, beta, beta0, state, n_steps, dt, mass, impl,
         e, fj = force_fn(disp[..., 0], disp[..., 1], disp[..., 2],
                          nbr_idx, mask)
         f = np.asarray(fj)
-        acc = f / mass * ACC_CONV
+        acc = f / m * ACC_CONV
         state.vel = state.vel + 0.5 * dt * acc
         state.step += 1
         if it % log_every == 0 or it == n_steps - 1:

@@ -31,6 +31,24 @@ def paper_box(natoms: int = 2000, a: float = 3.1652):
     return pos[:natoms] if len(pos) >= natoms else pos, box
 
 
+def random_species(natoms: int, fraction: float, seed: int = 0):
+    """Element index per site of a two-element substitutional alloy:
+    ``round(fraction * natoms)`` sites of element 1, the rest element 0,
+    chosen by a seeded permutation (int32 [natoms])."""
+    n1 = int(round(fraction * natoms))
+    species = np.zeros(natoms, np.int32)
+    species[np.random.default_rng(seed).permutation(natoms)[:n1]] = 1
+    return species
+
+
+def bcc_alloy(nx: int, ny: int, nz: int, a: float, fraction: float,
+              seed: int = 0):
+    """Two-element bcc box: (positions, box, species) with a ``fraction``
+    of the sites element 1 (:func:`random_species`)."""
+    pos, box = bcc_lattice(nx, ny, nz, a)
+    return pos, box, random_species(len(pos), fraction, seed)
+
+
 def perturb(pos, scale: float, seed: int = 0):
     rng = np.random.default_rng(seed)
     return pos + rng.normal(scale=scale, size=pos.shape)

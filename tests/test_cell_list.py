@@ -53,6 +53,21 @@ def test_device_skin_build_and_shift_contract():
     assert (shifts[~mask] == 0).all()
 
 
+def test_device_unwrapped_positions_keep_pairs_and_shifts():
+    """Atoms that drifted whole boxes away (the MD loop's raw positions)
+    keep the wrapped build's pairs, slots and displacements; only the
+    shifts absorb the drift."""
+    pos, box = paper_box(natoms=250)
+    pos = perturb(pos, 0.08, seed=4)
+    drift = np.random.default_rng(4).integers(-3, 4, size=pos.shape) * box
+    w = cell_neighbors_device(pos, box, 4.0, max_nbors=60, skin=0.7)
+    u = cell_neighbors_device(pos + drift, box, 4.0, max_nbors=60, skin=0.7)
+    np.testing.assert_array_equal(w[0], u[0])
+    np.testing.assert_array_equal(w[1], u[1])
+    np.testing.assert_allclose(u[2], w[2], atol=1e-9)
+    assert (u[3][~u[1]] == 0).all()
+
+
 def test_device_overflow_flags():
     """Capacity violations surface as the host builders' exceptions, driven
     by the device-side flags rather than in-trace raises."""

@@ -17,15 +17,17 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.indices import build_index
-from repro.kernels.snap_fused_de_half import snap_fused_de_half_pallas
-from repro.kernels.snap_u import snap_u_half_pallas
+from repro.kernels.snap_fused_de_half import (snap_de_species_pallas,
+                                              snap_fused_de_half_pallas)
+from repro.kernels.snap_u import snap_u_half_pallas, snap_u_species_pallas
 from repro.kernels.snap_y import (Y_HALF_TILE, Y_TILE, _y_coo_tiles,
                                   _y_half_coo_tiles, snap_y_half_pallas,
-                                  snap_y_pallas)
+                                  snap_y_pallas, snap_y_species_pallas)
 
 NATOMS_PAD, NNBOR = 2048, 26
 GEO = dict(rcut=4.7, rmin0=0.0, rfac0=0.99363, switch_flag=True,
@@ -165,3 +167,58 @@ def test_y_kernel_keeps_its_plane_interface(force_pipeline_hlo):
     operands = re.search(
         r'operand_layout_constraints=\{((?:[^{}]|\{[^{}]*\})*)\}', line)
     assert operands.group(1).count(f'f32[{hn}]') == 2, operands.group(1)
+
+
+def species_case(name, twojmax, natoms_pad, nnbor):
+    """(fn, shapes) of one species kernel: the five-channel per-pair
+    array, two elements' Y coefficients and the lane element plane."""
+    f32 = jnp.float32
+    geo = dict(rmin0=0.0, rfac0=0.99363, switch_flag=True, interpret=False)
+    nh = build_index(twojmax).idxu_half_max
+    disp = ((nnbor, 5, natoms_pad), f32)
+    plane = ((nh, natoms_pad), f32)
+    if name == 'u':
+        return (lambda d: snap_u_species_pallas(d, twojmax=twojmax, **geo),
+                [disp])
+    if name == 'de':
+        return (lambda d, yr, yi: snap_de_species_pallas(
+            d, yr, yi, twojmax=twojmax, **geo), [disp, plane, plane])
+    ntiles = _y_half_coo_tiles(twojmax, Y_HALF_TILE)[0].shape[0]
+    return (lambda ur, ui, c, s: snap_y_species_pallas(
+        ur, ui, c, s, twojmax=twojmax, interpret=False),
+        [plane, plane, ((2, ntiles, 1, Y_HALF_TILE), f32),
+         ((natoms_pad,), jnp.int32)])
+
+
+@pytest.mark.parametrize('name', ['u', 'y', 'de'])
+@pytest.mark.parametrize('natoms_pad,nnbor', [(NATOMS_PAD, NNBOR),
+                                              (16000, 72)])
+def test_species_kernel_compiles_for_v5e(one_chip, name, natoms_pad, nnbor):
+    """The species kernels at 2J=8: the paper's box and the W-Be MD
+    cell's 16,000 atoms with 72-slot lists."""
+    fn, shapes = species_case(name, 8, natoms_pad, nnbor)
+    assert compile_for(one_chip, fn, *shapes) == 1
+
+
+def test_species_force_pipeline_compiles_for_v5e(one_chip):
+    """The whole two-element kernel force call at 2J=8 holds exactly the
+    three species kernels, each named after its pallas_call."""
+    from repro.core.snap import SnapConfig, energy_forces
+    cfg = SnapConfig(twojmax=8, rcutfac=4.8123, radii=(0.5, 0.417932),
+                     weights=(1.0, 0.959049))
+    f32 = jnp.float32
+    pair = (2000, NNBOR)
+    species = jnp.asarray(np.arange(2000) % 5 == 0, jnp.int32)
+    shapes = [((2, cfg.ncoeff), f32), (pair, f32), (pair, f32), (pair, f32),
+              (pair, jnp.int32), (pair, jnp.bool_)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    hlo = jax.jit(
+        lambda b, dx, dy, dz, ni, m: energy_forces(
+            cfg, b, 0.0, dx, dy, dz, ni, m, impl='kernel', interpret=False,
+            species=species)
+    ).lower(*args).compile().as_text()
+    names = re.findall(r'^\s*(?:ROOT )?%([\w.-]+) = .*'
+                       r'custom_call_target="tpu_custom_call"', hlo,
+                       flags=re.M)
+    kernels = ('snap_u_species', 'snap_y_species', 'snap_de_species')
+    assert sorted(n.rsplit('.', 1)[0] for n in names) == sorted(kernels)
