@@ -45,7 +45,7 @@ def _stage_rows(cfg, beta, dx, dy, dz, maskj, twojmax, natoms):
     from repro.kernels.snap_fused_de_half import snap_fused_de_half_pallas
     from repro.kernels.snap_u import snap_u_half_pallas, snap_u_pallas
     from repro.kernels.snap_y import (snap_y_half_pallas, snap_y_pallas,
-                                      y_coef, y_coef_half)
+                                      y_coef)
     idx = cfg.index
     rows = {}
 
@@ -61,7 +61,8 @@ def _stage_rows(cfg, beta, dx, dy, dz, maskj, twojmax, natoms):
                rfac0=cfg.rfac0, switch_flag=cfg.switch_flag,
                interpret=True)
     stage_fns = dict(
-        half=(snap_u_half_pallas, snap_y_half_pallas, y_coef_half,
+        # the half walk takes beta itself, the full kernel a table
+        half=(snap_u_half_pallas, snap_y_half_pallas, lambda b, _: b,
               snap_fused_de_half_pallas, 'half'),
         full=(snap_u_pallas, snap_y_pallas, y_coef,
               snap_fused_de_pallas, 'full'),

@@ -219,7 +219,7 @@ class SnapIndex:
     z_half_sig1: np.ndarray       # [nnz_half] +-1 conj factor on Im(u1)
     z_half_sig2: np.ndarray       # [nnz_half] +-1 conj factor on Im(u2)
     z_half_cg: np.ndarray         # [nnz_half] cg * mirror signs s1*s2
-    z_half_jjz: np.ndarray        # [nnz_half] idxz row (runtime beta gather)
+    z_half_jjz: np.ndarray        # [nnz_half] idxz row (y_fac, y_jjb index)
     # --- idxb ---
     idxb_max: int
     idxb_triples: tuple           # (j1, j2, j) with j >= j1 >= j2

@@ -15,10 +15,10 @@
 from .ops import (energy_forces_kernel, half_planes_to_full,
                   snap_dedr_kernel, snap_force_pipeline, snap_ui_kernel,
                   snap_yi_kernel)
-from .snap_y import (snap_y_half_pallas, snap_y_pallas, y_coef, y_coef_half)
+from .snap_y import snap_y_half_pallas, snap_y_pallas, y_coef
 
 __all__ = [
     'energy_forces_kernel', 'half_planes_to_full', 'snap_dedr_kernel',
     'snap_force_pipeline', 'snap_ui_kernel', 'snap_yi_kernel',
-    'snap_y_half_pallas', 'snap_y_pallas', 'y_coef', 'y_coef_half',
+    'snap_y_half_pallas', 'snap_y_pallas', 'y_coef',
 ]

@@ -21,13 +21,16 @@ entry and force assembly.  ``layout='full'`` keeps the v1 full-plane
 pipeline alive for A/B benchmarking (see benchmarks/b_kernels.py).
 ``mxu_dtype`` (half layout only) sets the precision of the Y walk's
 operands: ``jnp.bfloat16`` rounds its U rows, coefficients and products
-to bfloat16, with f32 accumulation.
+to bfloat16, with f32 accumulation.  The half walk takes beta itself and
+forms each entry's coefficient in the kernel; only the full-plane Y
+kernel takes a per-entry coefficient table built at the JAX level
+(``y_coef``).
 
 Multi-element SNAP (``cfg.species_path``, half layout only) runs its own
 three kernels, ``snap_u_species``, ``snap_y_species`` and
 ``snap_de_species``: the per-pair array gains a fifth channel (the pair's
 cutoff) and carries the neighbour's element weight in its mask channel,
-and Y selects each lane's coefficients from one table per element.  The
+and Y selects each lane's coefficients from its element's row of beta.  The
 single-element path keeps its kernels, operands and shapes.
 
 Names in a profile: each kernel's ``pallas_call`` is named (``snap_u_half``,
@@ -35,9 +38,9 @@ Names in a profile: each kernel's ``pallas_call`` is named (``snap_u_half``,
 ``snap_fused_de`` in the full layout; the species kernels above), which
 names its custom-call instruction in the optimized HLO.  The glue between
 them runs under the named scopes ``snap.layout``, ``snap.self_planes``,
-``snap.y_coef``, ``snap.assemble``, ``snap.energy`` and (species path)
-``snap.species``, which reach each op's ``op_name`` (the profiler's
-``tf_op``).
+``snap.y_coef`` (the full layout's coefficient table), ``snap.assemble``,
+``snap.energy`` and (species path) ``snap.species``, which reach each
+op's ``op_name`` (the profiler's ``tf_op``).
 """
 
 from __future__ import annotations
@@ -58,7 +61,7 @@ from .snap_fused_de_half import (snap_de_species_pallas,
                                  snap_fused_de_half_pallas)
 from .snap_u import snap_u_half_pallas, snap_u_pallas, snap_u_species_pallas
 from .snap_y import (Y_HALF_TILE, Y_TILE, snap_y_half_pallas, snap_y_pallas,
-                     snap_y_species_pallas, y_coef, y_coef_half)
+                     snap_y_species_pallas, y_coef)
 
 LAYOUTS = ('half', 'full')
 
@@ -156,8 +159,12 @@ def snap_force_pipeline(cfg: SnapConfig, beta, beta0, dx, dy, dz, nbr_idx,
     """Zero-relayout kernel pipeline: Pallas U -> Pallas Y -> Pallas fused dE.
 
     Every inter-stage tensor stays in the canonical [*, natoms_pad] device
-    layout; the per-entry Y coefficient (cg * y_fac * beta gather, no atom
-    axis) is the only stage input computed at the JAX level.
+    layout.  The half layout's Y walk takes beta as it is (cast to
+    ``dtype``) and forms each entry's coefficient ``cg * y_fac *
+    beta[y_jjb]`` in the kernel, so nothing beta-dependent is built per
+    call outside the kernels; the full layout's per-entry coefficient
+    (``y_coef``, no atom axis) is its one stage input computed at the
+    JAX level.
 
     layout='half' (default): all inter-stage planes are half-index
     ``[idxu_half_max, natoms_pad]`` — ~1.9x less HBM plane traffic, and
@@ -205,9 +212,7 @@ def snap_force_pipeline(cfg: SnapConfig, beta, beta0, dx, dy, dz, nbr_idx,
         ut_r, ut_i = snap_u_half_pallas(disp, **geo)
         with jax.named_scope('snap.self_planes'):
             ut_r = ut_r + _self_planes(cfg, dtype, 'half')   # elementwise
-        with jax.named_scope('snap.y_coef'):
-            coef = y_coef_half(beta, cfg.twojmax, y_tile).astype(dtype)
-        y_r, y_i = snap_y_half_pallas(ut_r, ut_i, coef, twojmax=cfg.twojmax,
+        y_r, y_i = snap_y_half_pallas(ut_r, ut_i, beta, twojmax=cfg.twojmax,
                                       tile=y_tile, mxu_dtype=mxu_dtype,
                                       interpret=interpret)
         dedr = snap_fused_de_half_pallas(disp, y_r, y_i, **geo)
@@ -240,7 +245,7 @@ def _species_pipeline(cfg: SnapConfig, beta, beta0, dx, dy, dz, nbr_idx,
                       mxu_dtype, shard):
     """The species path of :func:`snap_force_pipeline` (half layout):
     per-pair weight and cutoff ride in the per-pair array, per-element
-    coefficients in Y's tables, and each lane's element in a plane."""
+    coefficients in Y's SMEM, and each lane's element in a plane."""
     natoms = dx.shape[0]
     with jax.named_scope('snap.species'):
         sp_i, w_j, rc = species_pairs(cfg, species, nbr_idx, shard)
@@ -253,9 +258,7 @@ def _species_pipeline(cfg: SnapConfig, beta, beta0, dx, dy, dz, nbr_idx,
     ut_r, ut_i = snap_u_species_pallas(disp, **geo)
     with jax.named_scope('snap.self_planes'):
         ut_r = ut_r + _self_planes(cfg, dtype, 'half')
-    with jax.named_scope('snap.y_coef'):
-        coef = y_coef_half(beta, cfg.twojmax, y_tile).astype(dtype)
-    y_r, y_i = snap_y_species_pallas(ut_r, ut_i, coef, sp_lanes,
+    y_r, y_i = snap_y_species_pallas(ut_r, ut_i, beta, sp_lanes,
                                      twojmax=cfg.twojmax, tile=y_tile,
                                      mxu_dtype=mxu_dtype,
                                      interpret=interpret)
@@ -434,8 +437,7 @@ def snap_yi_kernel(cfg: SnapConfig, ulisttot, beta, dtype=jnp.float32,
     ut_r = jnp.pad(ut.real.T.astype(dtype), [(0, 0), (0, pad)])
     ut_i = jnp.pad(ut.imag.T.astype(dtype), [(0, 0), (0, pad)])
     if layout == 'half':
-        coef = y_coef_half(beta, cfg.twojmax, y_tile).astype(dtype)
-        y_r, y_i = snap_y_half_pallas(ut_r, ut_i, coef, twojmax=cfg.twojmax,
+        y_r, y_i = snap_y_half_pallas(ut_r, ut_i, beta, twojmax=cfg.twojmax,
                                       tile=y_tile, mxu_dtype=mxu_dtype,
                                       interpret=interpret)
         y_h = (y_r[:, :natoms] + 1j * y_i[:, :natoms]).T

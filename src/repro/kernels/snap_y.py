@@ -18,16 +18,22 @@ offset, forms the complex product in f32, scales it by the entry's
 coefficient ``cg * y_fac * beta[y_jjb]`` and sums it.  The table is
 sorted by destination and padded so every ``Y_GROUP`` entries share one:
 a group sums in registers and touches the Y scratch once.  Table offsets
-and coefficients stream through SMEM one chunk per step of the inner
-grid axis; the conjugation signs are folded into the factor rows (a
-conjugated factor reads the conj U half of the scratch), so the walk
-does no sign arithmetic.  The beta factor is a runtime [nnz] gather done
-once at the JAX level (no natoms axis), so the kernel is beta-agnostic
-and Z is never materialized — the paper's compute_Yi fusion.
+stream through SMEM one chunk per step of the inner grid axis; the
+conjugation signs are folded into the factor rows (a conjugated factor
+reads the conj U half of the scratch), so the walk does no sign
+arithmetic.  Z is never materialized — the paper's compute_Yi fusion.
+
+The coefficient is formed in the walk.  Its static part ``cg * y_fac``
+and the beta index ``y_jjb`` stream with the offsets; beta itself,
+``ncoeff`` floats, sits whole in SMEM.  Each entry then costs two more
+scalar loads and one scalar multiply, and a call with a fresh beta (the
+force call, a fitting loop, a served request) builds no per-entry table
+outside the kernel.
 
 :func:`snap_y_species_pallas` (multi-element SNAP) is the same walk
-with one coefficient table per element and a plane of each lane's
-element: each entry's coefficient is selected per lane.
+with beta ``[nelements, ncoeff]`` in SMEM and a plane of each lane's
+element: each entry forms one coefficient per element and selects it
+per lane.
 
 The walk issues the algorithm's 10 flops per entry and atom.  It
 replaced a one-hot MXU contraction that issued 170-760x that work at
@@ -202,16 +208,18 @@ def _y_half_coo_tiles(twojmax: int, tile: int):
     """Half-space COO table in walk order, padded to [ntiles, 1, chunk].
 
     Entries are sorted by destination and every destination's run is
-    padded to a multiple of ``Y_GROUP`` (pad entries: cg = 0), so each
-    group of ``Y_GROUP`` consecutive entries shares one destination.
+    padded to a multiple of ``Y_GROUP`` (pad entries: cgfac = 0), so
+    each group of ``Y_GROUP`` consecutive entries shares one destination.
     ``chunk`` is ``tile``, or less for a table shorter than that, which
     is then one chunk of whole loop iterations.
 
-    Returns (fac1, fac2, dest, cg, jjz): the two factors' rows in the
+    Returns (fac1, fac2, dest, cgfac, jjb): the two factors' rows in the
     walk's factor table ``[U; conj U]`` (``src + idxu_half_max`` where
     the mirror conjugates the factor, σ = -1), the destination of each
-    group ``[ntiles, 1, chunk // Y_GROUP]``, the mirror-folded CG
-    product, and the idxz row of each entry (runtime beta gather).
+    group ``[ntiles, 1, chunk // Y_GROUP]``, the static part of each
+    entry's coefficient, mirror-folded CG product times ``y_fac`` (float64),
+    and the beta index ``y_jjb`` of its idxz row: the walk's coefficient
+    is ``cgfac * beta[jjb]``.
     """
     step = Y_GROUP * Y_UNROLL
     assert tile % step == 0, (tile, step)
@@ -238,16 +246,8 @@ def _y_half_coo_tiles(twojmax: int, tile: int):
     return (place(idx.z_half_src1 + nh * (idx.z_half_sig1 < 0), np.int32),
             place(idx.z_half_src2 + nh * (idx.z_half_sig2 < 0), np.int32),
             group_dest.reshape(ntiles, 1, tile // Y_GROUP),
-            place(idx.z_half_cg, np.float64),
-            place(idx.z_half_jjz, np.int32))
-
-
-@lru_cache(maxsize=16)
-def _y_half_offsets(twojmax: int, tile: int, step: int):
-    """The walk's table as scratch row offsets: factor and destination
-    rows times ``step``, the scratch rows one table row spans."""
-    fac1, fac2, dest, _, _ = _y_half_coo_tiles(twojmax, tile)
-    return fac1 * step, fac2 * step, dest * step
+            place(idx.z_half_cg * idx.y_fac[idx.z_half_jjz], np.float64),
+            place(idx.y_jjb[idx.z_half_jjz], np.int32))
 
 
 def _y_half_block(nh: int, natoms_pad: int, itemsize: int):
@@ -269,32 +269,30 @@ def _y_half_block(nh: int, natoms_pad: int, itemsize: int):
 
 
 def _snap_y_half_kernel(*refs, nh, lane_tiles, rows, ngroups, ntiles,
-                        dtype, mxu_dtype, nspecies=0):
+                        ncoeff, dtype, mxu_dtype, nspecies=0):
     """One (lane block, COO chunk) step of the sparse walk.
 
     Table refs are SMEM scalars of one chunk (offsets already scaled to
-    scratch rows); ut/y refs are the ``[nh, lane_tiles * LANES]`` lane
-    block.  The scratches hold the block packed so that one table row is
-    ``2 * rows`` consecutive sublanes, Re then Im: lane tile ``c`` of
-    factor ``f`` sits at rows ``2 * rows * f + c`` and ``... + rows + c``
-    (``u_s`` holds U, then conj U; ``y_s`` the Y accumulator).  The
-    packing is a strided copy once per lane block; every entry then reads
-    and writes whole vregs.
+    scratch rows), ``beta_ref`` the whole beta in SMEM; ut/y refs are the
+    ``[nh, lane_tiles * LANES]`` lane block.  The scratches hold the block
+    packed so that one table row is ``2 * rows`` consecutive sublanes, Re
+    then Im: lane tile ``c`` of factor ``f`` sits at rows
+    ``2 * rows * f + c`` and ``... + rows + c`` (``u_s`` holds U, then
+    conj U; ``y_s`` the Y accumulator).  The packing is a strided copy
+    once per lane block; every entry then reads and writes whole vregs.
 
-    ``nspecies > 0`` (species path): one coefficient ref per element
-    after ``dest_ref``, and a ``[1, lane_tiles * LANES]`` element-index
-    block before the U planes, packed like one U row into ``sp_s``
-    (lane tile ``c`` at row ``c``); each entry's coefficient is selected
-    per lane from the element sets.
+    ``nspecies > 0`` (species path): ``beta_ref`` holds ``nspecies``
+    rows of ``ncoeff``, and a ``[1, lane_tiles * LANES]`` element-index
+    block comes before the U planes, packed like one U row into ``sp_s``
+    (lane tile ``c`` at row ``c``); each entry forms one coefficient per
+    element and selects it per lane.
     """
     if nspecies:
-        fac1_ref, fac2_ref, dest_ref = refs[:3]
-        coef_refs = refs[3:3 + nspecies]
-        (sp_ref, ut_r_ref, ut_i_ref, y_r_ref, y_i_ref, u_s, y_s,
-         sp_s) = refs[3 + nspecies:]
+        (fac1_ref, fac2_ref, dest_ref, cgfac_ref, jjb_ref, beta_ref, sp_ref,
+         ut_r_ref, ut_i_ref, y_r_ref, y_i_ref, u_s, y_s, sp_s) = refs
     else:
-        (fac1_ref, fac2_ref, dest_ref, coef_ref, ut_r_ref, ut_i_ref,
-         y_r_ref, y_i_ref, u_s, y_s) = refs
+        (fac1_ref, fac2_ref, dest_ref, cgfac_ref, jjb_ref, beta_ref,
+         ut_r_ref, ut_i_ref, y_r_ref, y_i_ref, u_s, y_s) = refs
     t = pl.program_id(1)
     step = 2 * rows
     rounding = jnp.dtype(mxu_dtype) != jnp.dtype(dtype)
@@ -324,17 +322,19 @@ def _snap_y_half_kernel(*refs, nh, lane_tiles, rows, ngroups, ntiles,
         x = u_s[pl.ds(pl.multiple_of(off, step), step), :]
         return x[:rows], x[rows:]
 
-    if nspecies:
-        spv = sp_s[...]
+    spv = sp_s[...] if nspecies else None
 
-        def coefficient(k):
-            c = coef_refs[0][k]
-            for e in range(1, nspecies):
-                c = jnp.where(spv == e, coef_refs[e][k], c)
-            return c
-    else:
-        def coefficient(k):
-            return coef_ref[k]
+    def coefficient(k):
+        """``cgfac * beta[jjb]`` on the scalar unit (one per element on
+        the species path, selected per lane); rounded to ``mxu_dtype``
+        as a vector, since the scalar unit has no bfloat16."""
+        cg, b = cgfac_ref[k], jjb_ref[k]
+        c = cg * beta_ref[b]
+        for e in range(1, nspecies):
+            c = jnp.where(spv == e, cg * beta_ref[e * ncoeff + b], c)
+        if rounding:
+            c = rnd(jnp.broadcast_to(c, (rows, LANES)))
+        return c
 
     def group(g):
         acc_r = jnp.zeros((rows, LANES), dtype)
@@ -365,39 +365,38 @@ def _snap_y_half_kernel(*refs, nh, lane_tiles, rows, ngroups, ntiles,
             y_i_ref[:, pl.ds(c * LANES, LANES)] = y_s[strided(rows + c), :]
 
 
-def y_coef_half(beta, twojmax: int, tile: int = Y_HALF_TILE):
-    """Runtime per-entry coefficient for the half-space COO table:
-    ``cg_folded * y_fac * beta[y_jjb]`` — mirror signs s1·s2 are already
-    inside ``cg_folded`` (``SnapIndex.z_half_cg``)."""
-    idx = build_index(twojmax)
-    _, _, _, cg, jjz = _y_half_coo_tiles(twojmax, tile)
-    betaj = jnp.asarray(idx.y_fac, beta.dtype) * beta[..., idx.y_jjb]
-    return jnp.asarray(cg, beta.dtype) * betaj[..., jjz]
+@lru_cache(maxsize=16)
+def _y_half_tables(twojmax: int, tile: int, step: int, dtype):
+    """The walk's SMEM tables: factor and destination rows as scratch row
+    offsets (rows times ``step``, the scratch rows one table row spans),
+    ``cgfac`` in the plane dtype, and ``jjb``."""
+    fac1, fac2, dest, cgfac, jjb = _y_half_coo_tiles(twojmax, tile)
+    return fac1 * step, fac2 * step, dest * step, cgfac.astype(dtype), jjb
 
 
-def _y_half_call(name, ut_r, ut_i, coefs, species, twojmax, tile,
+def _y_half_call(name, ut_r, ut_i, beta, species, twojmax, tile,
                  mxu_dtype, interpret):
-    """The walk's ``pallas_call``: ``coefs`` is one coefficient table, or
-    (species path) a list of one per element with the element-index
-    plane ``species`` [1, natoms_pad]."""
+    """The walk's ``pallas_call``: ``beta`` is [ncoeff], or (species
+    path) [nelements, ncoeff] with the element-index plane ``species``
+    [1, natoms_pad]."""
     idx = build_index(twojmax)
     nh, natoms_pad = ut_r.shape
     assert nh == idx.idxu_half_max and natoms_pad % LANES == 0
+    ncoeff = beta.shape[-1]
+    assert ncoeff == int(idx.y_jjb.max()) + 1, (beta.shape, twojmax)
     dtype = ut_r.dtype
     mxu_dtype = jnp.dtype(mxu_dtype) if mxu_dtype is not None else dtype
     lane_tiles, rows, vmem = _y_half_block(nh, natoms_pad,
                                            jnp.dtype(dtype).itemsize)
-    fac1, fac2, dest = _y_half_offsets(twojmax, tile, 2 * rows)
-    nspecies = 0 if species is None else len(coefs)
-    coefs = [coefs] if species is None else list(coefs)
-    for c in coefs:
-        assert c.shape == fac1.shape, (c.shape, fac1.shape)
-    coefs = [c.astype(mxu_dtype).astype(dtype) for c in coefs]
+    tables = _y_half_tables(twojmax, tile, 2 * rows, jnp.dtype(dtype))
+    nspecies = 0 if species is None else beta.shape[0]
+    beta = beta.reshape(-1).astype(dtype)
 
-    ntiles, _, chunk = fac1.shape
+    ntiles, _, chunk = tables[0].shape
     kernel = partial(_snap_y_half_kernel, nh=nh, lane_tiles=lane_tiles,
                      rows=rows, ngroups=chunk // Y_GROUP, ntiles=ntiles,
-                     dtype=dtype, mxu_dtype=mxu_dtype, nspecies=nspecies)
+                     ncoeff=ncoeff, dtype=dtype, mxu_dtype=mxu_dtype,
+                     nspecies=nspecies)
     width = lane_tiles * LANES
 
     def smem(n):
@@ -408,10 +407,11 @@ def _y_half_call(name, ut_r, ut_i, coefs, species, twojmax, tile,
         return pl.BlockSpec((n, width), lambda i, t: (I0, i),
                             pipeline_mode=pl.Buffered(1))
     plane = lanes(nh)
-    in_specs = ([smem(chunk), smem(chunk), smem(chunk // Y_GROUP)]
-                + [smem(chunk)] * len(coefs) + [plane, plane])
-    operands = [jnp.asarray(fac1), jnp.asarray(fac2), jnp.asarray(dest),
-                *coefs, ut_r, ut_i]
+    whole = pl.BlockSpec(beta.shape, lambda i, t: (I0,),
+                         memory_space=pltpu.SMEM)
+    in_specs = [smem(chunk), smem(chunk), smem(chunk // Y_GROUP),
+                smem(chunk), smem(chunk), whole, plane, plane]
+    operands = [*map(jnp.asarray, tables), beta, ut_r, ut_i]
     scratch = [pltpu.VMEM((2 * nh * 2 * rows, LANES), dtype),
                pltpu.VMEM((nh * 2 * rows, LANES), dtype)]
     if nspecies:
@@ -432,11 +432,11 @@ def _y_half_call(name, ut_r, ut_i, coefs, species, twojmax, tile,
     )(*operands)
 
 
-def snap_y_half_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_HALF_TILE,
+def snap_y_half_pallas(ut_r, ut_i, beta, *, twojmax, tile=Y_HALF_TILE,
                        mxu_dtype=None, interpret=None):
     """ut_r/ut_i: [idxu_half_max, natoms_pad] half Ulisttot planes (self
-    included); coef: [ntiles, 1, chunk] from :func:`y_coef_half` with
-    the same ``tile``.
+    included); beta: [ncoeff] linear-model coefficients, cast to the
+    plane dtype.
 
     Returns (y_r, y_i): [idxu_half_max, natoms_pad] adjoint half planes —
     exactly the left rows of :func:`repro.core.bispectrum.compute_ylist`
@@ -447,20 +447,20 @@ def snap_y_half_pallas(ut_r, ut_i, coef, *, twojmax, tile=Y_HALF_TILE,
     and the complex products to bfloat16 before they are scaled and
     summed; accumulation stays in the plane dtype.
     """
-    return _y_half_call('snap_y_half', ut_r, ut_i, coef, None, twojmax,
+    return _y_half_call('snap_y_half', ut_r, ut_i, beta, None, twojmax,
                         tile, mxu_dtype, interpret)
 
 
-def snap_y_species_pallas(ut_r, ut_i, coef, species, *, twojmax,
+def snap_y_species_pallas(ut_r, ut_i, beta, species, *, twojmax,
                           tile=Y_HALF_TILE, mxu_dtype=None, interpret=None):
-    """Half-plane Y of the species path: ``coef`` is [nelements, ntiles,
-    1, chunk] (:func:`y_coef_half` of a [nelements, ncoeff] beta) and
-    ``species`` [natoms_pad] the element index of every lane.  Each
+    """Half-plane Y of the species path: ``beta`` is [nelements, ncoeff]
+    and ``species`` [natoms_pad] the element index of every lane.  Each
     lane's Y uses its own element's coefficients, selected per table
-    entry (one vector select on the walk's ~10 operations an entry);
-    otherwise :func:`snap_y_half_pallas`'s contract."""
+    entry (one vector select an element on the walk's ~10 operations an
+    entry); otherwise :func:`snap_y_half_pallas`'s contract."""
     natoms_pad = ut_r.shape[1]
     assert species.shape == (natoms_pad,), species.shape
-    return _y_half_call('snap_y_species', ut_r, ut_i, list(coef),
+    assert beta.ndim == 2, beta.shape
+    return _y_half_call('snap_y_species', ut_r, ut_i, beta,
                         species.reshape(1, natoms_pad), twojmax, tile,
                         mxu_dtype, interpret)

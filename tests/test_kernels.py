@@ -153,25 +153,84 @@ def test_snap_y_kernel_parity(dtype, twojmax, layout, natoms, most,
                                atol=tol)
 
 
-def test_snap_y_half_kernel_vmap_matches_solo():
+@pytest.mark.parametrize('batch_beta', [False, True],
+                         ids=['shared-beta', 'beta-per-lane'])
+def test_snap_y_half_kernel_vmap_matches_solo(batch_beta):
     """A vmapped batch of half Y calls (``ForceServer``'s buckets) equals
-    the solo calls bit for bit."""
+    the solo calls bit for bit, with one beta for the batch or, as
+    ``ForceServer`` feeds it, one beta per batch lane."""
     twojmax, natoms_pad = 4, 256
     nh = build_index(twojmax).idxu_half_max
+    ncoeff = SnapConfig(twojmax=twojmax).ncoeff
     rng = np.random.default_rng(3)
     ur, ui = (jnp.asarray(rng.normal(size=(3, nh, natoms_pad)))
               for _ in range(2))
-    beta = jnp.asarray(rng.normal(size=SnapConfig(twojmax=twojmax).ncoeff))
-    coef = snap_y.y_coef_half(beta, twojmax)
+    beta = jnp.asarray(rng.normal(size=(3, ncoeff)))
 
-    def y(a, b):
-        return snap_y.snap_y_half_pallas(a, b, coef, twojmax=twojmax,
+    def y(a, b, c):
+        return snap_y.snap_y_half_pallas(a, b, c, twojmax=twojmax,
                                          interpret=True)
-    batch = jax.vmap(y)(ur, ui)
+    if batch_beta:
+        batch = jax.vmap(y)(ur, ui, beta)
+    else:
+        beta = jnp.broadcast_to(beta[0], beta.shape)
+        batch = jax.vmap(y, in_axes=(0, 0, None))(ur, ui, beta[0])
     for i in range(3):
-        for got, want in zip(batch, y(ur[i], ui[i])):
+        for got, want in zip(batch, y(ur[i], ui[i], beta[i])):
             np.testing.assert_array_equal(np.asarray(got[i]),
                                           np.asarray(want))
+
+
+def _y_half_rounded(idx, u, beta, mxu_dtype):
+    """float64 sum of the half walk's terms, each operand rounded as the
+    walk rounds it: U rows, each coefficient ``cg * y_fac * beta[y_jjb]``
+    formed in float32 from the index tables, and each complex product
+    formed in float32 from the rounded rows.  u: complex [nh, natoms]."""
+    def rnd(x):
+        return np.asarray(jnp.asarray(x, mxu_dtype).astype(jnp.float32))
+    f32 = np.float32
+    u_r, u_i = rnd(u.real.astype(f32)), rnd(u.imag.astype(f32))
+    jjz = idx.z_half_jjz
+    coef = rnd((idx.z_half_cg * idx.y_fac[jjz]).astype(f32)
+               * np.asarray(beta, f32)[idx.y_jjb[jjz]])
+    s1, s2 = idx.z_half_sig1.astype(f32), idx.z_half_sig2.astype(f32)
+    a_r, a_i = u_r[idx.z_half_src1], s1[:, None] * u_i[idx.z_half_src1]
+    b_r, b_i = u_r[idx.z_half_src2], s2[:, None] * u_i[idx.z_half_src2]
+    p_r, p_i = rnd(a_r * b_r - a_i * b_i), rnd(a_r * b_i + a_i * b_r)
+    y = np.zeros(u.shape, np.complex128)
+    np.add.at(y, idx.z_half_dest,
+              coef[:, None].astype(np.float64) * (p_r + 1j * p_i))
+    return y
+
+
+def test_snap_y_half_bf16_walk_rounds_rows_coefficients_products():
+    """The bfloat16 walk (``mxu_dtype``, the benchmark's control) equals,
+    to float32 accumulation, the sum of its terms with the U rows, the
+    coefficients formed in the kernel and the complex products each
+    rounded to bfloat16; and that sum is far from the float32 walk's."""
+    twojmax, natoms = 8, 200
+    idx = build_index(twojmax)
+    nh = idx.idxu_half_max
+    rng = np.random.default_rng(8)
+    u = rng.normal(size=(nh, natoms)) + 1j * rng.normal(size=(nh, natoms))
+    beta = rng.normal(size=SnapConfig(twojmax=twojmax).ncoeff)
+    pad = [(0, 0), (0, 256 - natoms)]
+    planes = [jnp.asarray(np.pad(x, pad), jnp.float32)
+              for x in (u.real, u.imag)]
+
+    def walk(mxu_dtype):
+        yr, yi = snap_y.snap_y_half_pallas(
+            *planes, jnp.asarray(beta, jnp.float32), twojmax=twojmax,
+            mxu_dtype=mxu_dtype, interpret=True)
+        return np.asarray(yr)[:, :natoms] + 1j * np.asarray(yi)[:, :natoms]
+    want = _y_half_rounded(idx, u, beta, jnp.bfloat16)
+    scale = float(np.abs(want).max())
+    got = walk(jnp.bfloat16)
+    np.testing.assert_allclose(got.real / scale, want.real / scale,
+                               atol=2e-6)
+    np.testing.assert_allclose(got.imag / scale, want.imag / scale,
+                               atol=2e-6)
+    assert np.abs(walk(None) - want).max() > 1e-4 * scale
 
 
 def test_snap_y_kernel_tile_sweep():
@@ -285,6 +344,49 @@ def test_kernel_pipeline_2j14_matches_autodiff(dtype, tol):
     assert rel < tol, rel
     np.testing.assert_allclose(float(e_k), float(e_g),
                                rtol=max(tol, 1e-11))
+
+
+def _gather_sizes(fn, *args):
+    """(element counts of every gather's output, number of Pallas calls)
+    in ``fn``'s jaxpr, its kernels' bodies included."""
+    from repro.analysis.jaxpr_passes import iter_eqns
+    eqns = [eqn for eqn, _ in iter_eqns(jax.make_jaxpr(fn)(*args))]
+    return ([int(np.prod(v.aval.shape)) for eqn in eqns
+             if eqn.primitive.name == 'gather' for v in eqn.outvars],
+            sum(eqn.primitive.name == 'pallas_call' for eqn in eqns))
+
+
+@pytest.mark.parametrize('nelements,twojmax', [(1, 14), (2, 8)],
+                         ids=['W-2j14', 'WBe-2j8'])
+def test_force_call_builds_no_per_entry_beta_table(nelements, twojmax):
+    """The jitted kernel force call at the benchmark's box (N=2000, 26
+    slots) with beta a runtime argument: no gather gives a table as long
+    as the Y walk's (beta into every CG entry) or as idxz (beta into
+    every Z row), per element.  The walk forms each coefficient from beta
+    in SMEM."""
+    from repro.core.snap import energy_forces
+    from repro.kernels.snap_y import Y_HALF_TILE, _y_half_coo_tiles
+    if nelements == 1:
+        cfg = SnapConfig(twojmax=twojmax, rcut=4.7)
+        kw = {}
+    else:
+        cfg = SnapConfig(twojmax=twojmax, rcutfac=4.8123,
+                         radii=(0.5, 0.417932), weights=(1.0, 0.959049))
+        kw = dict(species=jnp.asarray(np.arange(2000) % 5 == 0, jnp.int32))
+    beta_shape = (cfg.ncoeff,) if nelements == 1 else (2, cfg.ncoeff)
+    pair = (2000, 26)
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        (beta_shape, jnp.float32), (pair, jnp.float32), (pair, jnp.float32),
+        (pair, jnp.float32), (pair, jnp.int32), (pair, jnp.bool_))]
+    sizes, kernels = _gather_sizes(jax.jit(
+        lambda b, dx, dy, dz, ni, m: energy_forces(
+            cfg, b, 0.0, dx, dy, dz, ni, m, impl='kernel', interpret=True,
+            **kw)), *args)
+    # one table per element on the species path
+    table = nelements * _y_half_coo_tiles(twojmax, Y_HALF_TILE)[0].size
+    idxz = nelements * build_index(twojmax).y_jjb.size
+    assert kernels == 3, kernels
+    assert table not in sizes and idxz not in sizes, (table, idxz, sizes)
 
 
 def test_snap_y_kernel_parity_2j14():

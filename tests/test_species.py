@@ -20,7 +20,7 @@ from repro.kernels.ops import _species_layout, half_planes_to_full
 from repro.kernels.ref import ref_snap_fused_de, ref_snap_u
 from repro.kernels.snap_fused_de_half import snap_de_species_pallas
 from repro.kernels.snap_u import snap_u_species_pallas
-from repro.kernels.snap_y import snap_y_species_pallas, y_coef_half
+from repro.kernels.snap_y import snap_y_species_pallas
 from repro.md.integrate import (MDState, init_velocities, run_nve,
                                 species_pair_counts, temperature)
 from repro.md.lattice import bcc_alloy, perturb, random_species
@@ -213,8 +213,7 @@ def test_y_species_kernel_matches_per_atom_beta(stage_inputs):
     kr, ki = snap_u_species_pallas(d, twojmax=cfg.twojmax, interpret=True)
     kr = kr.at[np.asarray(idx.self_diag_half)].add(cfg.wself)
     sp_lanes = jnp.pad(sp_i, (0, d.shape[-1] - len(sp_i)))
-    coef = y_coef_half(beta, cfg.twojmax, 2048)
-    yr, yi = snap_y_species_pallas(kr, ki, coef, sp_lanes,
+    yr, yi = snap_y_species_pallas(kr, ki, beta, sp_lanes,
                                    twojmax=cfg.twojmax, interpret=True)
     ur, ui = half_planes_to_full(cfg, kr, ki)
     y_ref = bs.compute_ylist((ur + 1j * ui).T, beta[sp_lanes], idx)
@@ -222,6 +221,33 @@ def test_y_species_kernel_matches_per_atom_beta(stage_inputs):
     got[:, idx.half_to_full] = np.asarray((yr + 1j * yi).T)
     w = idx.dedr_weight > 0
     np.testing.assert_allclose(got[:, w], np.asarray(y_ref)[:, w],
+                               atol=1e-12 * float(np.abs(y_ref).max()))
+
+
+@pytest.mark.parametrize('twojmax', [4, 8])
+def test_y_species_walk_forms_each_elements_coefficients(twojmax):
+    """The species walk, beta [2, ncoeff] in SMEM, against
+    ``compute_ylist`` with each lane's own beta row: random U planes (the
+    half rows, mirrored to the full ones), 200 atoms, so the second lane
+    tile is partial, and a lane element pattern with both elements in
+    every tile."""
+    cfg = wbe(twojmax)
+    idx = cfg.index
+    natoms, lanes = 200, 256
+    rng = np.random.default_rng(twojmax)
+    kr, ki = (jnp.asarray(rng.normal(size=(idx.idxu_half_max, lanes)))
+              for _ in range(2))
+    sp_lanes = jnp.asarray(rng.integers(0, 2, size=lanes), jnp.int32)
+    beta, _ = coefficients(cfg, seed=twojmax)
+    yr, yi = snap_y_species_pallas(kr, ki, beta, sp_lanes,
+                                   twojmax=twojmax, interpret=True)
+    ur, ui = half_planes_to_full(cfg, kr, ki)
+    u = (ur + 1j * ui).T[:natoms]
+    y_ref = np.asarray(bs.compute_ylist(u, beta[sp_lanes[:natoms]], idx))
+    got = np.zeros_like(y_ref)
+    got[:, idx.half_to_full] = np.asarray((yr + 1j * yi).T)[:natoms]
+    w = idx.dedr_weight > 0
+    np.testing.assert_allclose(got[:, w], y_ref[:, w],
                                atol=1e-12 * float(np.abs(y_ref).max()))
 
 

@@ -25,8 +25,7 @@ from repro.core.indices import build_index
 from repro.kernels.snap_fused_de_half import (snap_de_species_pallas,
                                               snap_fused_de_half_pallas)
 from repro.kernels.snap_u import snap_u_half_pallas, snap_u_species_pallas
-from repro.kernels.snap_y import (Y_HALF_TILE, Y_TILE, _y_coo_tiles,
-                                  _y_half_coo_tiles, snap_y_half_pallas,
+from repro.kernels.snap_y import (Y_TILE, _y_coo_tiles, snap_y_half_pallas,
                                   snap_y_pallas, snap_y_species_pallas)
 
 NATOMS_PAD, NNBOR = 2048, 26
@@ -68,9 +67,14 @@ def compile_for(one_chip, fn, *shapes):
     return text.count('custom_call_target="tpu_custom_call"')
 
 
+def ncoeff(twojmax):
+    return int(build_index(twojmax).y_jjb.max()) + 1
+
+
 def kernel_case(name, twojmax, natoms_pad=NATOMS_PAD):
     """(fn, shapes) of one kernel at the paper's sizes: half layout, except
-    ``y_full`` (the full-plane A/B Y kernel)."""
+    ``y_full`` (the full-plane A/B Y kernel).  The half Y walk takes beta
+    itself, which it holds whole in SMEM."""
     f32 = jnp.float32
     nh = build_index(twojmax).idxu_half_max
     disp = ((NNBOR, 4, natoms_pad), f32)
@@ -87,11 +91,10 @@ def kernel_case(name, twojmax, natoms_pad=NATOMS_PAD):
         return (lambda ur, ui, c: snap_y_pallas(
             ur, ui, c, twojmax=twojmax, interpret=False),
             [((nu, NATOMS_PAD), f32)] * 2 + [((ntiles, 1, Y_TILE), f32)])
-    ntiles = _y_half_coo_tiles(twojmax, Y_HALF_TILE)[0].shape[0]
     mxu = jnp.bfloat16 if name == 'y_bf16' else None
-    return (lambda ur, ui, c: snap_y_half_pallas(
-        ur, ui, c, twojmax=twojmax, mxu_dtype=mxu, interpret=False),
-        [plane, plane, ((ntiles, 1, Y_HALF_TILE), f32)])
+    return (lambda ur, ui, b: snap_y_half_pallas(
+        ur, ui, b, twojmax=twojmax, mxu_dtype=mxu, interpret=False),
+        [plane, plane, ((ncoeff(twojmax),), f32)])
 
 
 @pytest.mark.parametrize('name,twojmax', [
@@ -171,7 +174,7 @@ def test_y_kernel_keeps_its_plane_interface(force_pipeline_hlo):
 
 def species_case(name, twojmax, natoms_pad, nnbor):
     """(fn, shapes) of one species kernel: the five-channel per-pair
-    array, two elements' Y coefficients and the lane element plane."""
+    array, two elements' beta rows and the lane element plane."""
     f32 = jnp.float32
     geo = dict(rmin0=0.0, rfac0=0.99363, switch_flag=True, interpret=False)
     nh = build_index(twojmax).idxu_half_max
@@ -183,10 +186,9 @@ def species_case(name, twojmax, natoms_pad, nnbor):
     if name == 'de':
         return (lambda d, yr, yi: snap_de_species_pallas(
             d, yr, yi, twojmax=twojmax, **geo), [disp, plane, plane])
-    ntiles = _y_half_coo_tiles(twojmax, Y_HALF_TILE)[0].shape[0]
-    return (lambda ur, ui, c, s: snap_y_species_pallas(
-        ur, ui, c, s, twojmax=twojmax, interpret=False),
-        [plane, plane, ((2, ntiles, 1, Y_HALF_TILE), f32),
+    return (lambda ur, ui, b, s: snap_y_species_pallas(
+        ur, ui, b, s, twojmax=twojmax, interpret=False),
+        [plane, plane, ((2, ncoeff(twojmax)), f32),
          ((natoms_pad,), jnp.int32)])
 
 
